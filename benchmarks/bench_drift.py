@@ -19,17 +19,14 @@ Writes BENCH_drift.json at the repo root (tracked from PR 3 onward).
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
 
 import jax
 
 from repro.core import distclub, env, env_ops
 from repro.core.types import BanditHyper
 
-from .common import timed, emit
+from .common import timed, emit, run_cpu_child
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -54,7 +51,8 @@ hyper = BanditHyper(sigma=8, max_rounds=16, gamma=1.5, n_candidates=K)
 denv, _ = env.make_drift_env(jax.random.PRNGKey(0), N, D, CLUSTERS, K,
                              drift_period={drift_period}, n_phases=3)
 ops = env_ops.drift_ops(denv)
-mesh = jax.make_mesh((8,), ("users",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("users",))
 init_fn, epoch = distclub_shard.make_runtime(mesh, ("users",), N, D, hyper,
                                              ops=ops)
 state = init_fn(jax.random.PRNGKey(0))
@@ -112,25 +110,17 @@ def main(quick: bool = False):
     emit("drift_single_host_epoch", 1e6 * secs / epochs,
          f"reward/rand per phase {['%.3f' % r for r in ratios]}")
 
-    envv = dict(os.environ)
-    envv["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    envv["PYTHONPATH"] = str(ROOT / "src")
     code = _SHARDED_CODE.format(n=n, clusters=cfg["clusters"],
                                 drift_period=cfg["drift_period"],
                                 epochs=epochs)
-    out = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True, env=envv,
-                         timeout=900)
-    if out.returncode == 0:
-        parts = out.stdout.split()
-        payload["sharded_8dev"] = {
-            "epoch_s": float(parts[1]),
-            "reward_over_random": float(parts[3]),
-        }
-        emit("drift_sharded_8dev_epoch", 1e6 * float(parts[1]),
-             f"reward/rand {float(parts[3]):.3f}")
-    else:
-        payload["sharded_8dev"] = {"error": out.stderr[-800:]}
+    parts = run_cpu_child(code, 8).split()
+    payload["sharded_8dev"] = {
+        "epoch_s": float(parts[1]),
+        "reward_over_random": float(parts[3]),
+        "platform": "cpu",
+    }
+    emit("drift_sharded_8dev_epoch", 1e6 * float(parts[1]),
+         f"reward/rand {float(parts[3]):.3f}")
 
     (ROOT / "BENCH_drift.json").write_text(json.dumps(payload, indent=1))
 

@@ -43,10 +43,7 @@ Writes BENCH_retrieval.json at the repo root (tracked from PR 5 onward).
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +51,7 @@ import jax.numpy as jnp
 from repro.core import catalog as catalog_mod
 from repro.core.backend import BackendConfig
 
-from .common import emit, timed
+from .common import emit, timed, run_cpu_child
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -301,7 +298,8 @@ theta = e.theta
 def reward_fn(key, uids, ctx, choice):
     return env.step_rewards(key, theta[uids], ctx, choice)
 
-mesh = jax.make_mesh((8,), ("users",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("users",))
 session = serve.OnlineBandit.sharded(mesh, N_USERS, D, hyper,
                                      policy="distclub", refresh_every=0,
                                      backend="reference")
@@ -326,26 +324,15 @@ def _sharded_row(n_items=65536, batch=32):
     """8-device item-sharded two-stage serving transaction (host-platform
     mesh; 8 shards on one CPU core, so wall-clock is a smoke number —
     the modeled comm cut is the tracked metric)."""
-    envv = dict(os.environ)
-    envv["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    envv["PYTHONPATH"] = str(ROOT / "src")
-    out = subprocess.run(
-        [sys.executable, "-c",
-         _SHARDED_CODE.format(d=D, ks=KSHORT, batch=batch,
-                              n_items=n_items)],
-        capture_output=True, text=True, env=envv, timeout=900)
-    if out.returncode != 0 or "SHARD_STEP_US" not in out.stdout:
-        # raise, don't record-and-continue: run.py's failure policy makes
-        # the quick-bench step a real gate, and the comm metrics this row
-        # feeds are baseline-gated by check_regression
-        raise RuntimeError("sharded retrieval row failed:\n"
-                           + (out.stderr or out.stdout)[-800:])
-    us = float(out.stdout.split("SHARD_STEP_US")[1].split()[0])
+    stdout = run_cpu_child(
+        _SHARDED_CODE.format(d=D, ks=KSHORT, batch=batch, n_items=n_items),
+        8)
+    us = float(stdout.split("SHARD_STEP_US")[1].split()[0])
     emit(f"retrieval_step_sharded8_N{n_items}_B{batch}", us,
          f"comm_cut={comm_words_dense(batch, n_items) / comm_words_sharded(batch, D, KSHORT, 8):.1f}x")
     return {
         "N_items": n_items, "batch": batch, "d": D, "K_short": KSHORT,
-        "step_us": us,
+        "step_us": us, "platform": "cpu",
         "comm_bytes_merge_per_batch": 4 * comm_words_sharded(
             batch, D, KSHORT, 8),
         "comm_bytes_dense_gather_per_batch": 4 * comm_words_dense(

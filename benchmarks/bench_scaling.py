@@ -9,22 +9,18 @@ that is precisely the paper's Fig 6 divergence).
 """
 from __future__ import annotations
 
-import os
-import pathlib
-import subprocess
-import sys
+from .common import emit, run_cpu_child, save_json
 
-from .common import emit, save_json
-
-REPO = pathlib.Path(__file__).resolve().parents[1]
 
 _CODE = r"""
 import time, jax, jax.numpy as jnp
 from repro.distributed import distclub_shard
 from repro.core.types import BanditHyper
 
+from repro.launch.mesh import make_mesh
+
 n_dev = len(jax.devices())
-mesh = jax.make_mesh((n_dev,), ("users",))
+mesh = make_mesh((n_dev,), ("users",))
 hyper = BanditHyper(sigma=8, max_rounds=16, gamma=1.6, n_candidates=20)
 init_fn, epoch = distclub_shard.make_runtime(mesh, ("users",), n=2048, d=25,
                                              hyper=hyper)
@@ -42,21 +38,13 @@ print("EPOCH_S", (time.perf_counter() - t0) / 3)
 def main():
     rows = {}
     for n in (1, 2, 4, 8):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env["PYTHONPATH"] = str(REPO / "src")
-        out = subprocess.run([sys.executable, "-c", _CODE],
-                             capture_output=True, text=True, env=env,
-                             timeout=900)
-        if out.returncode != 0:
-            print(out.stderr[-2000:])
-            continue
-        t = float(out.stdout.split("EPOCH_S")[1].split()[0])
+        stdout = run_cpu_child(_CODE, n)
+        t = float(stdout.split("EPOCH_S")[1].split()[0])
         # analytic per-device comm for the paper's production scale
         n_users, d = 20_480, 25
         dclub_per_dev = 2 * (n_users // n) * (d * d + d) * 4
         dccb_per_dev = (n_users // n) * (5000 + 1) * (d * d + d) * 4
-        rows[n] = {"epoch_s": t,
+        rows[n] = {"epoch_s": t, "platform": "cpu",
                    "distclub_stage2_bytes_per_dev": dclub_per_dev,
                    "dccb_gossip_bytes_per_dev": dccb_per_dev}
         emit(f"fig6_scaling_dev{n}", 1e6 * t,

@@ -26,10 +26,7 @@ Writes BENCH_serve.json at the repo root (tracked from PR 4 onward).
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +36,7 @@ from repro.core import env, env_ops
 from repro.core.types import BanditHyper
 
 from .bench_interact import hbm_words_fused, hbm_words_reference
-from .common import emit, timed
+from .common import emit, timed, run_cpu_child
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -66,7 +63,8 @@ theta = e.theta
 def reward_fn(key, uids, ctx, choice):
     return env.step_rewards(key, theta[uids], ctx, choice)
 
-mesh = jax.make_mesh((8,), ("users",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("users",))
 session = serve.OnlineBandit.sharded(mesh, N, D, hyper, policy="distclub",
                                      refresh_every=0, backend="reference")
 ctx = jax.random.normal(jax.random.PRNGKey(1), (B, K, D))
@@ -155,19 +153,12 @@ def bench_shape(n, batch, repeats=3):
 
 
 def _sharded_row(n, batch):
-    envv = dict(os.environ)
-    envv["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    envv["PYTHONPATH"] = str(ROOT / "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _SHARDED_CODE.format(n=n, batch=batch)],
-        capture_output=True, text=True, env=envv, timeout=900)
-    if out.returncode != 0 or "SHARD_STEP_US" not in out.stdout:
-        return {"error": (out.stderr or out.stdout)[-800:]}
-    us = float(out.stdout.split("SHARD_STEP_US")[1].split()[0])
+    stdout = run_cpu_child(_SHARDED_CODE.format(n=n, batch=batch), 8)
+    us = float(stdout.split("SHARD_STEP_US")[1].split()[0])
     emit(f"serve_step_sharded8_n{n}_B{batch}", us,
          f"req/s={batch / (us * 1e-6):.0f}")
     return {"n_users": n, "batch": batch, "step_us": us,
-            "req_per_s": batch / (us * 1e-6)}
+            "req_per_s": batch / (us * 1e-6), "platform": "cpu"}
 
 
 def main(quick: bool = False):

@@ -10,12 +10,16 @@ comm volume) are the meaningful outputs, not absolute seconds.
 from __future__ import annotations
 
 import json
+import os
 import pathlib
+import subprocess
+import sys
 import time
 
 import jax
 
-RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results"
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+RESULTS = ROOT / "results"
 RESULTS.mkdir(parents=True, exist_ok=True)
 
 
@@ -37,3 +41,25 @@ def emit(name: str, us_per_call: float, derived: str = ""):
 
 def save_json(name: str, payload):
     (RESULTS / f"{name}.json").write_text(json.dumps(payload, indent=1))
+
+
+def run_cpu_child(code: str, n_devices: int, timeout: int = 900) -> str:
+    """Run ``code`` in a child Python on ``n_devices`` virtual CPU devices
+    and return its stdout.
+
+    The child is pinned to the CPU (``JAX_PLATFORMS=cpu``): a parent that
+    has touched JAX holds the accelerator, so a child that asked for it
+    would fail or hang.  A child that fails raises — no error row is
+    recorded in its place — so a broken multi-device path fails the
+    bench.  Rows built from the output carry ``"platform": "cpu"``."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["PYTHONPATH"] = str(ROOT / "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=timeout)
+    if out.returncode != 0:
+        raise RuntimeError(f"{n_devices}-device CPU child failed "
+                           f"(exit {out.returncode}):\n"
+                           + (out.stderr or out.stdout)[-2000:])
+    return out.stdout

@@ -89,6 +89,8 @@ def main(argv=None) -> int:
                          "(0 disables); a timeout is reported like any "
                          "other bench failure")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     failures: list[str] = []

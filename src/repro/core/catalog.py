@@ -62,10 +62,7 @@ import jax.numpy as jnp
 
 from .backend import Precision, resolve_precision
 
-try:  # PartitionSpec only needed for the sharded binding
-    from jax.sharding import PartitionSpec as P
-except ImportError:  # pragma: no cover
-    P = None
+from jax.sharding import PartitionSpec as P
 
 
 class Bank(NamedTuple):

@@ -12,6 +12,9 @@ Per interaction for user j:
 Gossip round (per user, with a random connected peer):
     compare *local* estimates (current + whole buffer);
     |w_i - w_peer| >= gamma (cb_i + cb_peer)  -> cut the edge, reset both;
+    (cb is CLUB's width at the number of interactions folded into the
+    statistics since their last reset, ``n_est`` — not the user's
+    lifetime count ``occ``, which also clocks the environment)
     identical neighbourhoods                  -> average buffers + current.
 
 Deviations (recorded per DESIGN.md §2):
@@ -44,7 +47,8 @@ class DCCBState(NamedTuple):
     bw: jnp.ndarray        # [n, d]
     Mbuf: jnp.ndarray      # [n, L, d, d] FIFO of pending Gram updates
     bbuf: jnp.ndarray      # [n, L, d]
-    occ: jnp.ndarray       # [n] i32
+    occ: jnp.ndarray       # [n] i32 lifetime interactions (env clock)
+    n_est: jnp.ndarray     # [n] i32 interactions in the stats since reset
     adj: jnp.ndarray       # [n, n] bool
     slot: jnp.ndarray      # [] i32 ring-buffer cursor (global: users advance in lockstep)
     comm_bytes: jnp.ndarray  # [] f32
@@ -58,6 +62,7 @@ def init_state(n_users: int, d: int, L: int) -> DCCBState:
         Mbuf=jnp.zeros((n_users, L, d, d), jnp.float32),
         bbuf=jnp.zeros((n_users, L, d), jnp.float32),
         occ=jnp.zeros((n_users,), jnp.int32),
+        n_est=jnp.zeros((n_users,), jnp.int32),
         # DCCB's gossip cuts individual edges with per-(i, peer) scatter
         # updates, so it keeps the small dense graph (n is modest for the
         # baseline); the packed representation is DistCLUB/CLUB's.
@@ -101,7 +106,8 @@ def buffered_push(s: DCCBState, x: jnp.ndarray, realized: jnp.ndarray,
     bbuf = s.bbuf.at[:, s.slot].set(jnp.where(m[:, None] > 0, upd_b, old_b))
     return s._replace(
         Mw=Mw, bw=bw, Mbuf=Mbuf, bbuf=bbuf,
-        occ=s.occ + mask.astype(jnp.int32), slot=(s.slot + 1) % L,
+        occ=s.occ + mask.astype(jnp.int32),
+        n_est=s.n_est + mask.astype(jnp.int32), slot=(s.slot + 1) % L,
     )
 
 
@@ -163,7 +169,11 @@ def gossip_round(state: DCCBState, key: jax.Array, hyper: BanditHyper,
     )
 
     dist = jnp.linalg.norm(w - w[peer], axis=-1)
-    width = clustering.cb_width(state.occ)
+    # a reset user's width follows its zeroed statistics: at its lifetime
+    # count it would claim a narrow ball around w = 0, be cut from its own
+    # cluster at the next gossip and reset that peer too — a cascade that
+    # erases what the graph has learned
+    width = clustering.cb_width(state.n_est)
     cut = (dist >= hyper.gamma * (width + width[peer])) & (peer != ids)
 
     # symmetric edge removal
@@ -194,11 +204,12 @@ def gossip_round(state: DCCBState, key: jax.Array, hyper: BanditHyper,
     bw = rs(avg(state.bw), jnp.zeros_like(state.bw))
     Mbuf = rs(avg(state.Mbuf), jnp.zeros_like(state.Mbuf))
     bbuf = rs(avg(state.bbuf), jnp.zeros_like(state.bbuf))
+    n_est = jnp.where(reset, 0, state.n_est)
 
     # paper Fig. 3 accounting: each exchange ships buffer + active objects
     nbytes = jnp.float32(n * (L + 1) * (d * d + d) * 4)
     return state._replace(
-        Mw=Mw, bw=bw, Mbuf=Mbuf, bbuf=bbuf, adj=adj,
+        Mw=Mw, bw=bw, Mbuf=Mbuf, bbuf=bbuf, n_est=n_est, adj=adj,
         comm_bytes=state.comm_bytes + nbytes,
     )
 

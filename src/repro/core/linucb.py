@@ -49,9 +49,18 @@ def ucb_scores(
     occ: jnp.ndarray,        # [] i32 interaction count
     alpha: float,
 ) -> jnp.ndarray:
-    """Paper's UCB(w, occ, context, Minv): returns [K] scores."""
-    estimate = contexts @ w
-    quad = jnp.einsum("kd,de,ke->k", contexts, Minv, contexts)
+    """Paper's UCB(w, occ, context, Minv): returns [K] scores.
+
+    Written as elementwise products and reductions, not matmuls: every
+    candidate row goes through the same arithmetic wherever it sits, so
+    identical candidates score bit-identically (a blocked matmul may round
+    rows in different micro-kernels differently) and the first-index
+    argmax tie-break is exact — the fused choose kernel relies on the
+    same property.  It also keeps the score f32-exact on TPU whatever the
+    default matmul precision."""
+    estimate = jnp.sum(contexts * w, axis=-1)
+    t = jnp.sum(Minv[None, :, :] * contexts[:, None, :], axis=-1)   # [K, d]
+    quad = jnp.sum(contexts * t, axis=-1)
     bonus = alpha * jnp.sqrt(jnp.maximum(quad, 0.0)) * jnp.sqrt(
         jnp.log1p(occ.astype(contexts.dtype))
     )

@@ -28,7 +28,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import clustering, linucb
@@ -147,11 +146,11 @@ def build_epoch_fn(mesh: Mesh, axes, n: int, d: int, L: int,
         return ShardedDCCB(Mw, bw, xbuf, rbuf, occ, comm), metrics
 
     specs = state_specs(axes)
-    return shard_map(
+    return jax.shard_map(
         epoch, mesh=mesh,
         in_specs=(specs, P()),
         out_specs=(specs, Metrics(P(), P(), P(), P())),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -30,7 +30,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import layers
@@ -315,11 +314,11 @@ def build_decode_step(mesh: Mesh, cfg: LMConfig, batch: int, s_max: int,
     s_spec = P(None, None, (ba or None), None, seq_ax)   # scale arrays
     cache_specs_t = ((c_spec, c_spec, s_spec, s_spec) if kv_quant
                      else (c_spec, c_spec))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(p_specs, tok_spec, cache_specs_t, P()),
         out_specs=(out_spec, cache_specs_t),
-        check_rep=False,
+        check_vma=False,
     )
     param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), p_specs,
                             is_leaf=lambda x: isinstance(x, P))

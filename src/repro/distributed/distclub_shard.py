@@ -37,7 +37,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.backend import BackendConfig, GraphBackend, InteractBackend
@@ -165,11 +164,11 @@ def build_epoch_fn(mesh: Mesh, axes: tuple[str, ...], n: int, d: int,
         return new_state, metrics, res.n_clusters
 
     specs = state_specs(axes)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         epoch, mesh=mesh,
         in_specs=(specs, P()),
         out_specs=(specs, Metrics(P(), P(), P(), P()), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return sharded
 

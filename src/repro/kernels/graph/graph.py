@@ -20,10 +20,23 @@ Two kernels over the bit-packed adjacency (layout in ``ref.py``):
            of adjacency instead of n^2 bool, plus O(n) label vectors.
            The label-chase ``min(l, l[l])`` stays outside (an O(n) gather).
 
+TPU layout.  A lane cannot be split into ``[words, 32]`` in registers, so
+the wrapper permutes each column tile of ``Bj`` columns (``Wb = Bj / 32``
+words) into bit-major order: tile-local column ``b Wb + w`` holds the
+original column ``32 w + b``.  Lane slice ``[b Wb, (b+1) Wb)`` then holds
+bit ``b`` of every word of the tile, packing is 32 aligned slices shifted
+and OR-ed, and unpacking is the same in reverse — no reshape.  The
+column-side vectors (``v_j``, its squared norms, ``cb_j``, ``labels_j``)
+are permuted in XLA next to the call, an O(n d) copy against the O(n^2/8)
+sweep; the packed adjacency itself keeps its layout.  The kernels see the
+adjacency as int32 (bit-identical; Mosaic has no unsigned reductions),
+per-row vectors as ``[R, 1]`` columns, per-column vectors as ``[1, C]``
+rows, and ``gamma`` in SMEM.
+
 Both kernels are shape-polymorphic over rows vs columns, so the sharded
 runtime reuses them unchanged on ``[n_local, n]`` row shards inside
 ``shard_map``.  Defaults (Bi=256, Bj=4096) make the packed tile
-``[256, 128]`` — exactly lane-width — and cost ~4.5 MiB VMEM at d=32.
+``[256, 128]`` — exactly lane-width, so the bit slices are whole vregs.
 """
 from __future__ import annotations
 
@@ -32,31 +45,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import BIG_LABEL
 
 
-def _prune_kernel(vi_ref, vj_ref, cbi_ref, cbj_ref, scal_ref,
-                  adj_ref, out_ref):
-    vi = vi_ref[...]            # [Bi, d]
-    vj = vj_ref[...]            # [Bj, d]
-    gamma = scal_ref[0]
-    d2 = (jnp.sum(vi * vi, axis=-1)[:, None]
-          + jnp.sum(vj * vj, axis=-1)[None, :]
+def _bit_major(a: jnp.ndarray, block_j: int) -> jnp.ndarray:
+    """Permute the leading (column) axis of ``a`` so tile-local position
+    ``b Wb + w`` holds column ``32 w + b`` of its ``block_j`` tile."""
+    C = a.shape[0]
+    wb = block_j // 32
+    t = a.reshape((C // block_j, wb, 32) + a.shape[1:])
+    return jnp.swapaxes(t, 1, 2).reshape(a.shape)
+
+
+def _prune_kernel(vi_ref, vj_ref, ni_ref, nj_ref, cbi_ref, cbj_ref,
+                  gamma_ref, adj_ref, out_ref):
+    d2 = (ni_ref[...] + nj_ref[...]
           - 2.0 * jax.lax.dot_general(
-              vi, vj,
+              vi_ref[...], vj_ref[...],
               dimension_numbers=(((1,), (1,)), ((), ())),
+              precision=jax.lax.Precision.HIGHEST,
               preferred_element_type=jnp.float32,
           ))                                              # [Bi, Bj]
     dist = jnp.sqrt(jnp.maximum(d2, 0.0))
-    thresh = gamma * (cbi_ref[...][:, None] + cbj_ref[...][None, :])
-    keep = dist < thresh                                  # [Bi, Bj]
+    keep = dist < gamma_ref[0] * (cbi_ref[...] + cbj_ref[...])
 
-    bi, bj = keep.shape
-    wb = bj // 32
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bi, wb, 32), 2)
-    words = jnp.sum(keep.reshape(bi, wb, 32).astype(jnp.uint32) << shifts,
-                    axis=-1, dtype=jnp.uint32)            # [Bi, Wb]
+    bi, wb = adj_ref.shape
+    words = jnp.zeros((bi, wb), jnp.int32)
+    for b in range(32):
+        words = words | (keep[:, b * wb:(b + 1) * wb].astype(jnp.int32) << b)
     out_ref[...] = adj_ref[...] & words
 
 
@@ -79,35 +97,44 @@ def prune_packed_pallas(
     assert R % block_i == 0, (R, block_i)
     assert C == Wp * 32 and C % block_j == 0, (C, Wp, block_j)
     wb = block_j // 32
-    grid = (R // block_i, C // block_j)
-    scal = jnp.array([gamma], jnp.float32)
-
-    return pl.pallas_call(
+    col = lambda i, j: (0, j)
+    out = pl.pallas_call(
         _prune_kernel,
-        grid=grid,
+        grid=(R // block_i, C // block_j),
         in_specs=[
             pl.BlockSpec((block_i, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_j, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-            pl.BlockSpec((block_j,), lambda i, j: (j,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_j), col),
+            pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_j), col),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_i, wb), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((block_i, wb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, Wp), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((R, Wp), jnp.int32),
         interpret=interpret,
-    )(v_i, v_j, cb_i, cb_j, scal, packed)
+        name="graph_prune",
+    )(v_i, _bit_major(v_j, block_j),
+      jnp.sum(v_i * v_i, axis=-1).reshape(R, 1),
+      _bit_major(jnp.sum(v_j * v_j, axis=-1), block_j).reshape(1, C),
+      cb_i.reshape(R, 1), _bit_major(cb_j, block_j).reshape(1, C),
+      jnp.asarray(gamma, jnp.float32).reshape(1),
+      jax.lax.bitcast_convert_type(packed, jnp.int32))
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 def _cc_hop_kernel(adj_ref, lself_ref, lj_ref, out_ref):
     j = pl.program_id(1)
-    adj = adj_ref[...]                # [Bi, Wb] u32
+    adj = adj_ref[...]                # [Bi, Wb] i32 (packed bits)
     bi, wb = adj.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bi, wb, 32), 2)
-    bits = ((adj[:, :, None] >> shifts) & jnp.uint32(1)) > 0
-    # label of column 32*w + b sits at lj[w, b] after the row-major reshape
-    neigh = jnp.where(bits, lj_ref[...].reshape(1, wb, 32), BIG_LABEL)
-    m = jnp.min(jnp.min(neigh, axis=2), axis=1)          # [Bi]
+    m = jnp.full((bi, wb), BIG_LABEL, jnp.int32)
+    for b in range(32):
+        bit = ((adj >> b) & 1) > 0
+        # lane slice b of the bit-major labels: the columns of bit b
+        lab = jnp.broadcast_to(lj_ref[:, b * wb:(b + 1) * wb], (bi, wb))
+        m = jnp.minimum(m, jnp.where(bit, lab, BIG_LABEL))
+    m = jnp.min(m, axis=1, keepdims=True)                 # [Bi, 1]
 
     @pl.when(j == 0)
     def _():
@@ -134,17 +161,19 @@ def cc_hop_packed_pallas(
     assert R % block_i == 0, (R, block_i)
     assert C == Wp * 32 and C % block_j == 0, (C, Wp, block_j)
     wb = block_j // 32
-    grid = (R // block_i, C // block_j)
-
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _cc_hop_kernel,
-        grid=grid,
+        grid=(R // block_i, C // block_j),
         in_specs=[
             pl.BlockSpec((block_i, wb), lambda i, j: (i, j)),
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-            pl.BlockSpec((block_j,), lambda i, j: (j,)),
+            pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_j), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_i,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((R,), jnp.int32),
+        out_specs=pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, 1), jnp.int32),
         interpret=interpret,
-    )(packed, labels_self, labels_j)
+        name="cc_hop",
+    )(jax.lax.bitcast_convert_type(packed, jnp.int32),
+      labels_self.reshape(R, 1),
+      _bit_major(labels_j, block_j).reshape(1, C))
+    return out[:, 0]

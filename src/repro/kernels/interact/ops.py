@@ -7,6 +7,8 @@ pad here is a trace-time no-op — no copies are issued.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -54,7 +56,10 @@ def choose(
         cp = jnp.zeros((np_, Kp, dp), jnp.float32).at[:n, :K, :d].set(contexts)
         op = jnp.zeros((np_,), occ.dtype).at[:n].set(occ)
 
+    # the padding block bounds HBM layout; the kernel's VMEM block is a
+    # divisor of it small enough for the [Bu, d, K] working set
     choice, x = choose_pallas(
-        wp, Mp, cp, op, alpha, k_live, block_users=bu, interpret=interpret
+        wp, Mp, cp, op, alpha, k_live, block_users=math.gcd(bu, 32),
+        interpret=interpret,
     )
     return choice[:n], x[:n, :d]

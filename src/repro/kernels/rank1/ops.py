@@ -1,6 +1,8 @@
 """Public entry point for the fused rank-1 bandit-state update."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -10,8 +12,11 @@ from .ref import rank1_update_inv_ref, rank1_update_ref
 
 
 def _dims(n: int, d: int, block_users: int):
+    """(n_pad, d_pad, kernel block): users pad to the padding block, and
+    the kernel runs a divisor of it that keeps the [Bu, d, d] working set
+    (double-buffered in and out) well inside VMEM."""
     np_, bu = user_block(n, block_users)
-    return np_, round_up(d, SUB), bu
+    return np_, round_up(d, SUB), math.gcd(bu, 64)
 
 
 def rank1_update(

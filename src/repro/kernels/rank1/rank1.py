@@ -7,8 +7,12 @@ user's state once into VMEM, applies all three updates, and writes once —
 the update is memory-bound, so this is a ~3x HBM-traffic cut on the state
 arrays (the §Perf hillclimb for the bandit cell measures exactly this).
 
-Grid: one step per block of users.  All compute is batched elementwise /
-dot_general over the user block, so the VPU/MXU stay on the fast path.
+Grid: one step per block of users.  The per-user matvec ``Minv x`` is a
+VPU multiply plus lane reduction over the ``[Bu, d, d]`` block (Mosaic
+rejects the batched ``dot_general``), and the row copy of ``Minv x`` the
+outer product needs is taken through an identity mask — exact, since it
+adds zeros.  ``r`` and ``mask`` are ``[n, 1]`` columns: 1-D blocks do not
+match XLA's TPU layout.
 """
 from __future__ import annotations
 
@@ -19,29 +23,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _sherman_morrison(minv, xm):
+    """(M + x x')^-1 from M^-1 for a block: minv [Bu, d, d] f32, xm [Bu, d]."""
+    d = minv.shape[-1]
+    mx_col = jnp.sum(minv * xm[:, None, :], axis=2, keepdims=True)  # [Bu,d,1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (1, d, d), 1)
+           == jax.lax.broadcasted_iota(jnp.int32, (1, d, d), 2))
+    mx_row = jnp.sum(jnp.where(eye, mx_col, 0.0), axis=1,
+                     keepdims=True)                                   # [Bu,1,d]
+    denom = 1.0 + jnp.sum(xm[:, None, :] * mx_row, axis=2, keepdims=True)
+    return minv - mx_col * mx_row / denom
+
+
 def _rank1_kernel(m_ref, minv_ref, b_ref, x_ref, r_ref, mask_ref,
                   m_out, minv_out, b_out):
-    M = m_ref[...]             # [Bu, d, d] (always f32)
     # Minv may arrive bf16 (Precision state_dtype): upcast once in VMEM so
     # the S-M math runs f32; for f32 inputs the astype is a no-op.
-    Minv = minv_ref[...].astype(jnp.float32)   # [Bu, d, d]
-    b = b_ref[...]             # [Bu, d]
-    x = x_ref[...]             # [Bu, d]
-    r = r_ref[...]             # [Bu]
-    msk = mask_ref[...]        # [Bu] (f32 0/1)
-
-    xm = x * msk[:, None]
-    Mx = jax.lax.dot_general(
-        Minv, xm,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                  # [Bu, d]
-    denom = 1.0 + jnp.sum(xm * Mx, axis=-1)            # [Bu]
-    outer_inv = Mx[:, :, None] * Mx[:, None, :]        # [Bu, d, d]
-    minv_out[...] = (Minv - outer_inv / denom[:, None, None]).astype(
-        minv_out.dtype)
-    m_out[...] = M + xm[:, :, None] * xm[:, None, :]
-    b_out[...] = b + (r * msk)[:, None] * x
+    x = x_ref[...]                 # [Bu, d]
+    msk = mask_ref[...]            # [Bu, 1] (f32 0/1)
+    xm = x * msk
+    minv_out[...] = _sherman_morrison(
+        minv_ref[...].astype(jnp.float32), xm).astype(minv_out.dtype)
+    m_out[...] = m_ref[...] + xm[:, :, None] * xm[:, None, :]
+    b_out[...] = b_ref[...] + r_ref[...] * msk * x
 
 
 def _rank1_inv_kernel(minv_ref, b_ref, x_ref, r_ref, mask_ref,
@@ -49,23 +53,19 @@ def _rank1_inv_kernel(minv_ref, b_ref, x_ref, r_ref, mask_ref,
     """M-free variant: the sharded runtime drops the Gram matrix entirely
     (stage-2 recovers it by inversion), so its hot loop only touches Minv
     and b — 2 state passes instead of 4."""
-    Minv = minv_ref[...].astype(jnp.float32)   # [Bu, d, d] (may be bf16)
-    b = b_ref[...]             # [Bu, d]
-    x = x_ref[...]             # [Bu, d]
-    r = r_ref[...]             # [Bu]
-    msk = mask_ref[...]        # [Bu] (f32 0/1)
+    x = x_ref[...]                 # [Bu, d]
+    msk = mask_ref[...]            # [Bu, 1] (f32 0/1)
+    xm = x * msk
+    minv_out[...] = _sherman_morrison(
+        minv_ref[...].astype(jnp.float32), xm).astype(minv_out.dtype)
+    b_out[...] = b_ref[...] + r_ref[...] * msk * x
 
-    xm = x * msk[:, None]
-    Mx = jax.lax.dot_general(
-        Minv, xm,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )                                                  # [Bu, d]
-    denom = 1.0 + jnp.sum(xm * Mx, axis=-1)            # [Bu]
-    outer_inv = Mx[:, :, None] * Mx[:, None, :]        # [Bu, d, d]
-    minv_out[...] = (Minv - outer_inv / denom[:, None, None]).astype(
-        minv_out.dtype)
-    b_out[...] = b + (r * msk)[:, None] * x
+
+def _specs(block_users: int, d: int):
+    bs2 = pl.BlockSpec((block_users, d, d), lambda i: (i, 0, 0))
+    bs1 = pl.BlockSpec((block_users, d), lambda i: (i, 0))
+    bs0 = pl.BlockSpec((block_users, 1), lambda i: (i, 0))
+    return bs2, bs1, bs0
 
 
 @functools.partial(jax.jit, static_argnames=("block_users", "interpret"))
@@ -76,15 +76,13 @@ def rank1_update_inv_pallas(
     r: jnp.ndarray,      # [n]
     mask: jnp.ndarray,   # [n] f32 (0/1)
     *,
-    block_users: int = 256,
+    block_users: int = 64,
     interpret: bool = False,
 ):
     n, d = b.shape
     assert n % block_users == 0
     grid = (n // block_users,)
-    bs2 = pl.BlockSpec((block_users, d, d), lambda i: (i, 0, 0))
-    bs1 = pl.BlockSpec((block_users, d), lambda i: (i, 0))
-    bs0 = pl.BlockSpec((block_users,), lambda i: (i,))
+    bs2, bs1, bs0 = _specs(block_users, d)
     return pl.pallas_call(
         _rank1_inv_kernel,
         grid=grid,
@@ -95,7 +93,8 @@ def rank1_update_inv_pallas(
             jax.ShapeDtypeStruct((n, d), jnp.float32),
         ],
         interpret=interpret,
-    )(Minv, b, x, r, mask)
+        name="rank1_update_inv",
+    )(Minv, b, x, r.reshape(n, 1), mask.reshape(n, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("block_users", "interpret"))
@@ -107,15 +106,13 @@ def rank1_update_pallas(
     r: jnp.ndarray,      # [n]
     mask: jnp.ndarray,   # [n] f32 (0/1)
     *,
-    block_users: int = 256,
+    block_users: int = 64,
     interpret: bool = False,
 ):
     n, d = b.shape
     assert n % block_users == 0
     grid = (n // block_users,)
-    bs2 = pl.BlockSpec((block_users, d, d), lambda i: (i, 0, 0))
-    bs1 = pl.BlockSpec((block_users, d), lambda i: (i, 0))
-    bs0 = pl.BlockSpec((block_users,), lambda i: (i,))
+    bs2, bs1, bs0 = _specs(block_users, d)
     return pl.pallas_call(
         _rank1_kernel,
         grid=grid,
@@ -127,4 +124,5 @@ def rank1_update_pallas(
             jax.ShapeDtypeStruct((n, d), jnp.float32),
         ],
         interpret=interpret,
-    )(M, Minv, b, x, r, mask)
+        name="rank1_update",
+    )(M, Minv, b, x, r.reshape(n, 1), mask.reshape(n, 1))

@@ -22,8 +22,8 @@ def rank1_update_ref(
     Minv32 = Minv.astype(jnp.float32)
     m = mask.astype(x.dtype)
     xm = x * m[:, None]
-    Mx = jnp.einsum("nij,nj->ni", Minv32, xm)
-    denom = 1.0 + jnp.einsum("ni,ni->n", xm, Mx)
+    Mx = jnp.sum(Minv32 * xm[:, None, :], axis=-1)     # elementwise: exact
+    denom = 1.0 + jnp.sum(xm * Mx, axis=-1)            # f32 at any precision
     Minv_new = Minv32 - jnp.einsum("ni,nj->nij", Mx, Mx) / denom[:, None,
                                                                  None]
     M_new = M + jnp.einsum("ni,nj->nij", xm, xm)
@@ -48,8 +48,8 @@ def rank1_update_inv_ref(
     Minv32 = Minv.astype(jnp.float32)
     m = mask.astype(x.dtype)
     xm = x * m[:, None]
-    Mx = jnp.einsum("nij,nj->ni", Minv32, xm)
-    denom = 1.0 + jnp.einsum("ni,ni->n", xm, Mx)
+    Mx = jnp.sum(Minv32 * xm[:, None, :], axis=-1)     # elementwise: exact
+    denom = 1.0 + jnp.sum(xm * Mx, axis=-1)            # f32 at any precision
     Minv_new = Minv32 - jnp.einsum("ni,nj->nij", Mx, Mx) / denom[:, None,
                                                                  None]
     b_new = b + (r * m)[:, None] * x

@@ -54,27 +54,38 @@ INT_MAX = jnp.iinfo(jnp.int32).max
 NEG_INF = -jnp.inf
 
 
-def select_topk(buf_s: jnp.ndarray, buf_i: jnp.ndarray, k: int):
+def select_topk(buf_s, buf_i, k: int):
     """Top-``k`` of each row of ``(buf_s [n, W], buf_i [n, W])`` by
     (score desc, id asc) — repeated (max score, min id) selection, so the
     result depends only on the (score, id) value multiset, never on the
     buffer order.  Returns ``(scores [n, k], ids [n, k])`` sorted the
-    same way.  Shared verbatim by the oracle and the Pallas kernel."""
-    n = buf_s.shape[0]
+    same way.  Shared verbatim by the oracle and the Pallas kernel.
+
+    ``buf_s``/``buf_i`` may also be tuples of ``[n, W_p]`` parts — the
+    selection over the parts equals the selection over their
+    concatenation (it is value-based), and the kernel passes its running
+    shortlist and the new tile as two parts because Mosaic cannot
+    concatenate at a lane offset that is not a multiple of 128."""
+    parts_s = tuple(buf_s) if isinstance(buf_s, (tuple, list)) else (buf_s,)
+    parts_i = tuple(buf_i) if isinstance(buf_i, (tuple, list)) else (buf_i,)
+    n = parts_s[0].shape[0]
     cols = jax.lax.broadcasted_iota(jnp.int32, (n, k), 1)
 
     def step(j, carry):
-        buf, out_s, out_i = carry
-        m = jnp.max(buf, axis=1)                           # [n]
-        tied = buf == m[:, None]
-        sel = jnp.min(jnp.where(tied, buf_i, INT_MAX), axis=1)
-        buf = jnp.where(tied & (buf_i == sel[:, None]), NEG_INF, buf)
+        bufs, out_s, out_i = carry
+        m = functools.reduce(jnp.maximum, [
+            jnp.max(b, axis=1, keepdims=True) for b in bufs])      # [n, 1]
+        sel = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(b == m, i, INT_MAX), axis=1, keepdims=True)
+            for b, i in zip(bufs, parts_i)])                         # [n, 1]
+        bufs = tuple(jnp.where((b == m) & (i == sel), NEG_INF, b)
+                     for b, i in zip(bufs, parts_i))
         put = cols == j
-        out_s = jnp.where(put, m[:, None], out_s)
-        out_i = jnp.where(put, sel[:, None], out_i)
-        return buf, out_s, out_i
+        out_s = jnp.where(put, m, out_s)
+        out_i = jnp.where(put, sel, out_i)
+        return bufs, out_s, out_i
 
-    init = (buf_s,
+    init = (parts_s,
             jnp.full((n, k), NEG_INF, jnp.float32),
             jnp.full((n, k), -1, jnp.int32))
     _, out_s, out_i = jax.lax.fori_loop(0, k, step, init)
@@ -201,9 +212,14 @@ def tile_bounds(
     Minv = Minv.astype(jnp.float32)     # bf16 state: eigvalsh wants f32
     lmax = jnp.linalg.eigvalsh(Minv)[:, -1]            # [n] largest eig
     sl = jnp.sqrt(jnp.maximum(lmax, 0.0))
-    est = w @ tile_mu.T + jnp.linalg.norm(w, axis=1)[:, None] * tile_r[None]
+    # HIGHEST: a bf16 matmul pass (the TPU default for f32) errs by far
+    # more than BOUND_SLACK, and the bound must dominate the f32 scores
+    hi = jax.lax.Precision.HIGHEST
+    est = (jnp.dot(w, tile_mu.T, precision=hi)
+           + jnp.linalg.norm(w, axis=1)[:, None] * tile_r[None])
     G = (tile_mu[:, None, :] * tile_mu[:, :, None]).reshape(T, d * d)
-    qmu = jnp.sqrt(jnp.maximum(Minv.reshape(n, d * d) @ G.T, 0.0))
+    qmu = jnp.sqrt(jnp.maximum(
+        jnp.dot(Minv.reshape(n, d * d), G.T, precision=hi), 0.0))
     conf = jnp.minimum(qmu + sl[:, None] * tile_r[None],
                        sl[:, None] * tile_xn[None])
     widen = jnp.sqrt(jnp.log1p(occ.astype(jnp.float32)))

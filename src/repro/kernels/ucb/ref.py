@@ -12,8 +12,11 @@ def ucb_scores_ref(
     alpha: float,
 ) -> jnp.ndarray:
     """scores[n, K] = contexts.w + alpha sqrt(ctx Minv ctx) sqrt(log1p(occ))."""
-    est = jnp.einsum("nkd,nd->nk", contexts, w)
-    quad = jnp.einsum("nkd,nde,nke->nk", contexts, Minv, contexts)
+    # elementwise form shared with ``core.linucb.ucb_scores``: identical
+    # candidates score bit-identically (see there)
+    est = jnp.sum(contexts * w[:, None, :], axis=-1)
+    t = jnp.sum(Minv[:, None, :, :] * contexts[:, :, None, :], axis=-1)
+    quad = jnp.sum(contexts * t, axis=-1)
     bonus = alpha * jnp.sqrt(jnp.maximum(quad, 0.0)) * jnp.sqrt(
         jnp.log1p(occ.astype(contexts.dtype))
     )[:, None]

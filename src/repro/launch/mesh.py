@@ -15,13 +15,26 @@ over ("pod", "data") on the multi-pod mesh via ``batch_axes``.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """THE mesh constructor of this repository: every axis ``Auto``.
+
+    ``jax.make_mesh`` makes ``Explicit`` axes by default, under which a
+    scatter into an item-sharded array (``core.catalog``'s staging) must
+    name its ``out_sharding``.  The programs here place state with
+    ``NamedSharding`` and shard explicitly with ``jax.shard_map``, so the
+    compiler propagates everything else."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh: Mesh):

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import configs
+from . import compile_cache
 
 
 def serve_recsys(spec, args):
@@ -97,6 +98,7 @@ def main():
                     choices=["distclub", "dccb", "club", "linucb"],
                     help="serving policy (recsys archs)")
     args = ap.parse_args()
+    compile_cache.enable()
     spec = configs.get(args.arch)
     if spec.family == "lm":
         serve_lm(spec, args)

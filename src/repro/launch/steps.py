@@ -185,7 +185,6 @@ def build_gnn_cell(spec, shape: str, mesh: Mesh) -> CellBundle:
     partitioned by destination block (dst in the local node shard) — the
     production graph-partitioning layout, making segment reductions local.
     """
-    from jax.experimental.shard_map import shard_map
 
     cfg = spec.cell_cfg(shape)
     cell = spec.shapes[shape]
@@ -205,11 +204,11 @@ def build_gnn_cell(spec, shape: str, mesh: Mesh) -> CellBundle:
         return params, opt, loss
 
     n_spec = P(axes)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(), P(axes, None), n_spec, n_spec, n_spec, n_spec),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     p_sh = _shard_tree(mesh, jax.tree.map(lambda _: P(), params_abs))
     opt_sh = optimizer.AdamWState(

@@ -22,6 +22,7 @@ from .. import configs
 from ..core.types import BanditHyper
 from ..train import optimizer
 from ..train.checkpoint import CheckpointManager
+from . import compile_cache
 
 
 def _reduced_cfg(spec):
@@ -158,6 +159,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = configs.get(args.arch)
     if spec.family == "lm":

@@ -51,10 +51,7 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
-try:  # PartitionSpec only needed for the sharded binding
-    from jax.sharding import PartitionSpec as P
-except ImportError:  # pragma: no cover
-    P = None
+from jax.sharding import PartitionSpec as P
 
 
 class PendingBuffer(NamedTuple):

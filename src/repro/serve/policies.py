@@ -58,10 +58,7 @@ from ..core.types import BanditHyper, ClusterStats, DistCLUBState, GraphState
 from ..kernels.graph import ops as graph_ops
 from ..runtime import stages
 
-try:  # PartitionSpec only needed for the sharded binding
-    from jax.sharding import PartitionSpec as P
-except ImportError:  # pragma: no cover
-    P = None
+from jax.sharding import PartitionSpec as P
 
 POLICIES = ("distclub", "dccb", "club", "linucb")
 

@@ -84,7 +84,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import catalog as catalog_mod
@@ -480,11 +479,11 @@ def _bind_tx(policy, body, mesh, axes, out_extra=(), out_override=None):
         out_specs = specs
 
     def wrap(state, *args):
-        mapped = shard_map(
+        mapped = jax.shard_map(
             bound, mesh=mesh,
             in_specs=(specs,) + tuple(P() for _ in args),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return mapped(state, *args)
 
@@ -529,9 +528,9 @@ def _bind_catalog_tx(policy, body, mesh, axes, n_plain, out_specs,
                 + (catalog_mod.specs(axes),) + tuple(tail_specs))
 
     def wrap(state, *args):
-        mapped = shard_map(
+        mapped = jax.shard_map(
             bound, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return mapped(state, *args)
 
@@ -586,9 +585,9 @@ def _bind_pending_tx(policy, body, mesh, axes, n_plain, out_specs, *,
                 + tuple(plain) + tuple(tail_specs))
 
     def wrap(state, *args):
-        mapped = shard_map(
+        mapped = jax.shard_map(
             bound, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return mapped(state, *args)
 

@@ -269,7 +269,8 @@ def test_conservation_and_parity_8dev_item_sharded():
                 return serve.OnlineBandit.create(
                     N, D, hyper, policy="distclub", refresh_every=2 * N,
                     pending_capacity=256, pending_ttl=16), cat
-            mesh = jax.make_mesh((8,), ("users",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("users",))
             s = serve.OnlineBandit.sharded(
                 mesh, N, D, hyper, policy="distclub",
                 refresh_every=2 * N, pending_capacity=256,

@@ -48,7 +48,8 @@ def test_sharded_dccb_runs_and_ships_buffers():
         from repro.distributed import dccb_shard
         from repro.core.types import BanditHyper
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         hyper = BanditHyper(alpha=0.05, gamma=1.5, n_candidates=10)
         n, d, L = 64, 8, 8
         init_fn, epoch = dccb_shard.make_runtime(

@@ -37,7 +37,8 @@ def test_sharded_distclub_learns_on_8_devices():
         from repro.distributed import distclub_shard
         from repro.core.types import BanditHyper
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         hyper = BanditHyper(sigma=8, max_rounds=16, gamma=1.5, n_candidates=10)
         init_fn, epoch = distclub_shard.make_runtime(
             mesh, ("data", "model"), n=64, d=8, hyper=hyper)
@@ -74,7 +75,8 @@ def test_decode_shard_map_matches_reference():
         ref, _ = tr.lm_decode_step(params, cfg, toks[:, S], (kc, vc),
                                    jnp.int32(S))
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         step, p_sh, c_sh = decode_shard.build_decode_step(mesh, cfg, B, S + pad)
         params_d = jax.device_put(params, p_sh)
         kc_d = jax.device_put(kc, c_sh[0]); vc_d = jax.device_put(vc, c_sh[1])
@@ -140,11 +142,11 @@ def test_quantized_gather_matches_exact_loss():
     out = _run_with_devices("""
         import dataclasses
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.models import gnn
 
-        mesh = jax.make_mesh((8,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("d",))
         n, e = 128, 512
         cfg = gnn.GNNConfig(d_feat=16, n_classes=5)
         params = gnn.init_gat(jax.random.PRNGKey(0), cfg)
@@ -156,12 +158,12 @@ def test_quantized_gather_matches_exact_loss():
         mask = jnp.ones((n,), bool)
 
         def loss_with(cfg):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda p, fe, s, d_, l, m: gnn.gat_loss_local(
                     p, cfg, fe, s, d_, l, m, ("d",)),
                 mesh=mesh,
                 in_specs=(P(), P("d", None), P("d"), P("d"), P("d"), P("d")),
-                out_specs=P(), check_rep=False)
+                out_specs=P(), check_vma=False)
             return float(f(params, feats, src, dst, labels, mask))
 
         exact = loss_with(cfg)

@@ -179,7 +179,8 @@ def test_single_arm_parity_8dev_sharded():
         hyper = BanditHyper(sigma=4, max_rounds=1, gamma=1.5,
                             n_candidates=K)
         e, _ = env.make_synthetic_env(jax.random.PRNGKey(0), N, D, 4, K)
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         mk = lambda: serve.OnlineBandit.sharded(
             mesh, N, D, hyper, policy="distclub", refresh_every=2 * N,
             pending_capacity=128, pending_ttl=16)

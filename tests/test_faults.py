@@ -122,7 +122,8 @@ def test_delay0_parity_sharded_8dev():
         def reward_fn(key, uids, ctx, choice):
             return env.step_rewards(key, theta[uids], ctx, choice)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         sync = serve.OnlineBandit.sharded(mesh, N, D, hyper,
                                           policy="distclub",
                                           refresh_every=N)

@@ -155,6 +155,45 @@ def test_pruned_region_catalog_actually_skips():
     assert int(sk) / int(tot) > 0.3, (int(sk), int(tot))
 
 
+def test_pallas_pruned_skip_count_matches_natural_order_rule():
+    """The Pallas pruned stream visits tiles in natural order and skips a
+    tile for a user block iff every user's bound is below that user's
+    running shortlist floor — the k-th best live score among the tiles
+    before it.  Its skip counter must equal that rule evaluated on the
+    host, and the shortlist must still equal the unpruned one."""
+    d, N, tile, K, bu = 16, 4096, 256, 16, 8
+    cat, e = _region_catalog(jax.random.PRNGKey(5), N, d, regions=8,
+                             noise=0.01)
+    occ = jnp.full((e.theta.shape[0],), 50, jnp.int32)
+    cl = itemclub.build_clusters(cat, tile_items=tile)
+    Minv = jnp.broadcast_to(jnp.eye(d), (e.theta.shape[0], d, d))
+    tb0 = tile_bounds(e.theta, Minv, occ, 0.3, cl.tile_mu, cl.tile_r,
+                      cl.tile_xn, cl.tile_n)
+    # a segment-aware batch: users sharing a best tile share a block
+    order = jnp.argsort(jnp.argmax(tb0, axis=1), stable=True)
+    w, tb = e.theta[order], tb0[order]
+    Minv = Minv[order]
+    s1, i1, sk, tot = topk_ops.topk_pruned(
+        w, Minv, occ, cl.emb_sorted, cl.live_sorted, cl.perm, 0.3, K, tb,
+        use_pallas=True, block_users=bu, interpret=True)
+    _, i0 = topk_ref(w, Minv, occ, cat.serving.emb, cat.serving.live, 0.3, K)
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+
+    x = np.asarray(cl.emb_sorted, np.float64)
+    s = (np.asarray(w, np.float64) @ x.T
+         + 0.3 * np.linalg.norm(x, axis=1)[None] * np.sqrt(np.log1p(50.0)))
+    s = np.where(np.asarray(cl.live_sorted)[None] > 0, s, -np.inf)
+    T = N // tile
+    floors = np.full((w.shape[0], T), -np.inf)
+    for t in range(1, T):
+        seen = np.sort(s[:, :t * tile], axis=1)
+        floors[:, t] = seen[:, -K] if t * tile >= K else -np.inf
+    below = np.asarray(tb) < floors
+    want = below.reshape(-1, bu, T).all(axis=1).sum()
+    assert int(tot) == T * (w.shape[0] // bu)
+    assert 0 < want == int(sk), (want, int(sk))
+
+
 # ---------------------------------------------------------------------------
 # clustering structure
 # ---------------------------------------------------------------------------
@@ -391,7 +430,8 @@ def test_pruned_8dev_item_sharded_matches_single_host():
         def reward_fn(key, uids, ctx, choice):
             return env.step_rewards(key, theta[uids], ctx, choice)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         s1 = serve.OnlineBandit.create(N_USERS, D, hyper, policy="distclub")
         s8 = serve.OnlineBandit.sharded(mesh, N_USERS, D, hyper,
                                         policy="distclub")

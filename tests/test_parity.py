@@ -45,7 +45,8 @@ def test_distclub_single_host_vs_sharded_parity():
         R = 2 * hyper.max_rounds
         m1 = jax.tree.map(lambda v: np.asarray(v).reshape(E, R), m1)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         init_fn, epoch = distclub_shard.make_runtime(
             mesh, ("users",), N, D, hyper, ops=ops)
         st = init_fn(jax.random.PRNGKey(0))
@@ -108,7 +109,8 @@ def test_drift_scenario_per_stage_parity_sharded():
         R = hyper.max_rounds
         m1 = jax.tree.map(lambda v: np.asarray(v).reshape(E, 2 * R), m1)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         init_fn, epoch = distclub_shard.make_runtime(
             mesh, ("users",), N, D, hyper, ops=ops)
         st = init_fn(jax.random.PRNGKey(0))
@@ -154,7 +156,8 @@ def test_replay_scenario_runs_sharded():
         s1, m1, _ = distclub.run(ops, jax.random.PRNGKey(4), hyper,
                                  n_epochs=E, d=8)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         init_fn, epoch = distclub_shard.make_runtime(
             mesh, ("users",), 64, 8, hyper, ops=ops)
         st = init_fn(jax.random.PRNGKey(0))

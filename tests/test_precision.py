@@ -135,7 +135,8 @@ def test_f32_sharded_8dev_matches_single_host():
         def reward_fn(key, u, ctx, choice):
             return env.step_rewards(key, theta[u], ctx, choice)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         s1 = serve.OnlineBandit.create(N, D, hyper, policy="distclub",
                                        refresh_every=0,
                                        backend="reference",

@@ -298,7 +298,8 @@ def test_item_sharded_8dev_matches_single_host():
         def reward_fn(key, uids, ctx, choice):
             return env.step_rewards(key, theta[uids], ctx, choice)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         s1 = serve.OnlineBandit.create(N_USERS, D, hyper,
                                        policy="distclub",
                                        refresh_every=2 * N_USERS)

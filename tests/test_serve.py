@@ -175,7 +175,8 @@ def test_step_sharded_8dev_matches_single_host():
         def reward_fn(key, uids, ctx, choice):
             return ops.rewards_fn(key, jnp.zeros_like(uids), ctx, choice, 0)
 
-        mesh = jax.make_mesh((8,), ("users",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("users",))
         s1 = serve.OnlineBandit.create(N, D, hyper, policy="distclub",
                                        refresh_every=2 * N)
         s8 = serve.OnlineBandit.sharded(mesh, N, D, hyper,

@@ -112,7 +112,8 @@ def test_checkpoint_crash_leaves_no_corruption(tmp_path):
 
 def test_checkpoint_elastic_restore_changes_sharding(tmp_path):
     """Restore onto a different 'mesh' (1-device) — elastic scaling."""
-    mesh = jax.make_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mgr = checkpoint.CheckpointManager(tmp_path)
@@ -225,3 +226,39 @@ def test_moe_grads_flow_to_all_parts():
     g = jax.grad(loss)(params)
     assert float(jnp.abs(g["router"]).sum()) > 0
     assert float(jnp.abs(g["experts"]["gate"]).sum()) > 0
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """The persistent compile cache follows JAX_COMPILATION_CACHE_DIR when
+    it is set (and sets nothing else); otherwise it sits at the fixed
+    <checkout>/.jax_cache, whatever the working directory."""
+    import pathlib
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.chdir(tmp_path)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(pathlib.Path(__file__).resolve().parents[1] / ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert compile_cache.enable() == want
+        if env_dir is None:
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_bench_cpu_child_is_pinned_to_cpu_and_fails_loudly():
+    """Benchmark children never ask for the accelerator (the parent holds
+    it) and a failed child fails its bench instead of leaving a row."""
+    from benchmarks.common import run_cpu_child
+    out = run_cpu_child("import jax; print(jax.default_backend(), "
+                        "len(jax.devices()))", 2)
+    assert out.split() == ["cpu", "2"]
+    with pytest.raises(RuntimeError, match="exit 3"):
+        run_cpu_child("import sys; sys.exit(3)", 1)
