@@ -1,0 +1,13 @@
+"""Device time of the ``choose`` kernel per four-stage epoch."""
+
+KERNELS = ('choose',)
+
+
+def read(ctx):
+    per = ctx.counters.get('epochs', 0)
+    if not per:
+        return None
+    t = sum(ctx.reduced.kernel_s.get(k, 0.0) for k in KERNELS)
+    if t <= 0:
+        return None
+    return 1e3 * t / per
