@@ -136,15 +136,16 @@ def stage2(state: DistCLUBState, hyper: BanditHyper, d: int,
         _NULL, gb, hyper, d,
         state.lin.Minv, state.lin.b, state.lin.occ, state.graph.adj,
     )
-    stats = ClusterStats(
-        Mc=res.Mc, Mcinv=jnp.linalg.inv(res.Mc), bc=res.bc,
-        size=res.size, seen=res.seen,
-    )
-    return state._replace(
-        graph=GraphState(adj=res.adj, labels=res.labels),
-        clusters=stats,
-        comm_bytes=state.comm_bytes + res.comm_bytes,
-    )
+    with jax.named_scope("stage2"):
+        with jax.named_scope("cluster_inverse"):
+            Mcinv = jnp.linalg.inv(res.Mc)
+        stats = ClusterStats(Mc=res.Mc, Mcinv=Mcinv, bc=res.bc,
+                             size=res.size, seen=res.seen)
+        return state._replace(
+            graph=GraphState(adj=res.adj, labels=res.labels),
+            clusters=stats,
+            comm_bytes=state.comm_bytes + res.comm_bytes,
+        )
 
 
 def stage3(state: DistCLUBState, ops: EnvOps, key: jax.Array,
@@ -156,7 +157,8 @@ def stage3(state: DistCLUBState, ops: EnvOps, key: jax.Array,
     which this stage no longer advances (stage 4 reads the same stage-2
     snapshot in both runtimes)."""
     be = backend or _default_backend(state, hyper)
-    uMcinv, ubc, umean_occ = serving_snapshot(state)
+    with jax.named_scope("stage3"):
+        uMcinv, ubc, umean_occ = serving_snapshot(state)
     Minv, b, occ, metrics = stages.cluster_rounds(
         be, ops, hyper, state.lin.Minv, state.lin.b, state.lin.occ,
         state.c_rounds, key, 0, uMcinv, ubc, umean_occ,
@@ -167,8 +169,9 @@ def stage3(state: DistCLUBState, ops: EnvOps, key: jax.Array,
 def stage4(state: DistCLUBState, hyper: BanditHyper) -> DistCLUBState:
     """Rebalance per-user budgets between personalized / cluster rounds
     (against the stage-2 mean-occ snapshot — see the engine docstring)."""
-    umean_occ = stages.snapshot_mean_occ(
-        state.clusters.seen, state.clusters.size, state.graph.labels)
+    with jax.named_scope("stage4"):
+        umean_occ = stages.snapshot_mean_occ(
+            state.clusters.seen, state.clusters.size, state.graph.labels)
     u_rounds, c_rounds = stages.stage4_rebalance(
         hyper, state.lin.occ, umean_occ, state.u_rounds, state.c_rounds)
     return state._replace(u_rounds=u_rounds, c_rounds=c_rounds)
@@ -212,21 +215,28 @@ def _run(
     backend: InteractBackend,
     graph: GraphBackend,
 ) -> tuple[DistCLUBState, Metrics, jnp.ndarray]:
-    state = init_state(ops.n_users, d, hyper)
+    with jax.named_scope("init"):
+        state = init_state(ops.n_users, d, hyper)
+        keys = jax.random.split(key, n_epochs)
 
     def epoch(state, k):
         k1, k3 = jax.random.split(k)
         state, m1 = stage1(state, ops, k1, hyper, backend)
         state = stage2(state, hyper, d, graph)
-        n_clu = clustering.num_clusters(state.graph.labels)
+        with jax.named_scope("stage2"):
+            n_clu = clustering.num_clusters(state.graph.labels)
         state, m3 = stage3(state, ops, k3, hyper, backend)
         state = stage4(state, hyper)
-        metrics = jax.tree.map(
-            lambda a, b: jnp.concatenate([a, b]), m1, m3
-        )
+        with jax.named_scope("round_metrics"):
+            metrics = jax.tree.map(
+                lambda a, b: jnp.concatenate([a, b]), m1, m3
+            )
         return state, (metrics, n_clu)
 
-    keys = jax.random.split(key, n_epochs)
-    state, (metrics, n_clusters) = jax.lax.scan(epoch, state, keys)
-    metrics = jax.tree.map(lambda x: x.reshape(-1), metrics)
-    return refresh_gram(state), metrics, n_clusters
+    # the epoch loop's own work (key split, loop bookkeeping, stacking the
+    # metrics) is named ``epoch``; each stage inside it names itself
+    with jax.named_scope("epoch"):
+        state, (metrics, n_clusters) = jax.lax.scan(epoch, state, keys)
+        metrics = jax.tree.map(lambda x: x.reshape(-1), metrics)
+    with jax.named_scope("refresh_gram"):
+        return refresh_gram(state), metrics, n_clusters

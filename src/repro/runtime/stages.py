@@ -37,6 +37,14 @@ The interaction loop (``interaction_rounds``) is also the inner loop of
 both DCCB drivers (buffered updates are just a different ``update_fn``),
 so all four bandit runtimes share one round protocol:
 env draw -> score -> fused choose -> env reward -> update -> metrics.
+
+Named scopes: each stage opens a ``jax.named_scope`` (``stage1`` ..
+``stage4``), each round step names its parts (``env_contexts``,
+``score``, ``choose``, ``env_rewards``, ``fold``, ``round_metrics``) and
+stage 2 names ``prune``, ``cc``, ``gram_inverse``, ``cluster_reduce`` and
+``cluster_inverse``.  XLA keeps them in each instruction's ``op_name``
+metadata, so a device profile charges every operation to a stage; they
+change nothing else in the compiled program.
 """
 from __future__ import annotations
 
@@ -97,16 +105,23 @@ def interaction_rounds(be, ops, hyper, key, carry0, *, row0, n_steps,
         occ_log = be.unpad_users(occ)
         mask = (jnp.ones(occ.shape, bool) if budget_p is None
                 else step_idx < budget_p)
-        contexts = ops.contexts_fn(k_ctx, occ_log, row0)
-        w, minv_eff = score_fn(carry)
-        x, choice = be.choose(w, minv_eff, contexts, occ, hyper.alpha)
-        realized, expected, best, rand = ops.rewards_fn(
-            k_rew, occ_log, contexts, be.unpad_users(choice), row0
-        )
-        carry = update_fn(carry, step_idx, x, realized, mask)
-        return carry, _metrics_of(
-            realized, expected, best, rand, be.unpad_users(mask)
-        )
+        with jax.named_scope("env_contexts"):
+            contexts = ops.contexts_fn(k_ctx, occ_log, row0)
+        with jax.named_scope("score"):
+            w, minv_eff = score_fn(carry)
+        with jax.named_scope("choose"):
+            x, choice = be.choose(w, minv_eff, contexts, occ, hyper.alpha)
+        with jax.named_scope("env_rewards"):
+            realized, expected, best, rand = ops.rewards_fn(
+                k_rew, occ_log, contexts, be.unpad_users(choice), row0
+            )
+        with jax.named_scope("fold"):
+            carry = update_fn(carry, step_idx, x, realized, mask)
+        with jax.named_scope("round_metrics"):
+            metrics = _metrics_of(
+                realized, expected, best, rand, be.unpad_users(mask)
+            )
+        return carry, metrics
 
     steps = jnp.arange(n_steps)
     keys = jax.random.split(key, n_steps)
@@ -144,8 +159,9 @@ def personalized_rounds(be, ops, hyper, Minv, b, occ, budget, key, row0):
         Minv_, b_, _ = carry
         return linucb.user_vector(Minv_, b_), Minv_
 
-    return _bandit_rounds(be, ops, hyper, Minv, b, occ, budget, key, row0,
-                          score_own)
+    with jax.named_scope("stage1"):
+        return _bandit_rounds(be, ops, hyper, Minv, b, occ, budget, key,
+                              row0, score_own)
 
 
 def beta_gate(hyper, occ, umean_occ):
@@ -173,19 +189,19 @@ def cluster_rounds(be, ops, hyper, Minv, b, occ, budget, key, row0,
     ``umean_occ``, from :func:`stage2_refresh`) are FROZEN for the whole
     stage (the paper's lazy semantics): they are padded and the cluster
     user-vector computed once, outside the scan."""
-    uMcinv_p = be.pad_gram(uMcinv)
-    ubc_p = be.pad_vec(ubc)
-    v_clu = linucb.user_vector(uMcinv_p, ubc_p)
-    umean_p = be.pad_users(umean_occ)
-
     def score_cluster(carry):
         Minv_, b_, occ_ = carry
         use_own = beta_gate(hyper, occ_, umean_p)
         v_own = linucb.user_vector(Minv_, b_)
         return mix_scores(use_own, v_own, v_clu, Minv_, uMcinv_p)
 
-    return _bandit_rounds(be, ops, hyper, Minv, b, occ, budget, key, row0,
-                          score_cluster)
+    with jax.named_scope("stage3"):
+        uMcinv_p = be.pad_gram(uMcinv)
+        ubc_p = be.pad_vec(ubc)
+        v_clu = linucb.user_vector(uMcinv_p, ubc_p)
+        umean_p = be.pad_users(umean_occ)
+        return _bandit_rounds(be, ops, hyper, Minv, b, occ, budget, key,
+                              row0, score_cluster)
 
 
 # ---------------------------------------------------------------------------
@@ -285,30 +301,41 @@ def stage2_refresh(col, gb, hyper, d, Minv, b, occ, adj) -> Stage2Refresh:
     n_local = Minv.shape[0]
     row0 = col.axis_index() * n_local
 
-    # serving sessions may carry Minv in a reduced Precision state dtype;
-    # the solves/inversions here run in f32 (no-op upcast for f32 state)
-    Minv = Minv.astype(jnp.float32)
-    v_local = linucb.user_vector(Minv, b)                     # [n_local, d]
-    v_all = col.all_gather(v_local)                           # [n, d]
-    occ_all = col.all_gather(occ)                             # [n]
-    adj = gb.prune_rows(adj, v_local, occ, v_all, occ_all, hyper.gamma)
-    labels = connected_components(col, gb, adj, n, row0, n_local)
-    local_labels = jax.lax.dynamic_slice_in_dim(labels, row0, n_local)
+    with jax.named_scope("stage2"):
+        # serving sessions may carry Minv in a reduced Precision state
+        # dtype; the solves/inversions here run in f32 (no-op upcast for
+        # f32 state)
+        Minv = Minv.astype(jnp.float32)
+        with jax.named_scope("prune"):
+            v_local = linucb.user_vector(Minv, b)             # [n_local, d]
+            v_all = col.all_gather(v_local)                   # [n, d]
+            occ_all = col.all_gather(occ)                     # [n]
+            adj = gb.prune_rows(adj, v_local, occ, v_all, occ_all,
+                                hyper.gamma)
+        with jax.named_scope("cc"):
+            labels = connected_components(col, gb, adj, n, row0, n_local)
+            local_labels = jax.lax.dynamic_slice_in_dim(labels, row0,
+                                                        n_local)
 
-    eye = jnp.eye(d, dtype=jnp.float32)
-    M = jnp.linalg.inv(Minv)
-    Mc = col.psum(
-        jax.ops.segment_sum(M - eye, local_labels, num_segments=n)
-    ) + eye
-    bc = col.psum(jax.ops.segment_sum(b, local_labels, num_segments=n))
-    size = col.psum(jax.ops.segment_sum(
-        jnp.ones_like(local_labels), local_labels, num_segments=n))
-    seen = col.psum(jax.ops.segment_sum(occ, local_labels, num_segments=n))
+        eye = jnp.eye(d, dtype=jnp.float32)
+        with jax.named_scope("gram_inverse"):
+            M = jnp.linalg.inv(Minv)
+        with jax.named_scope("cluster_reduce"):
+            Mc = col.psum(
+                jax.ops.segment_sum(M - eye, local_labels, num_segments=n)
+            ) + eye
+            bc = col.psum(jax.ops.segment_sum(b, local_labels,
+                                              num_segments=n))
+            size = col.psum(jax.ops.segment_sum(
+                jnp.ones_like(local_labels), local_labels, num_segments=n))
+            seen = col.psum(jax.ops.segment_sum(occ, local_labels,
+                                                num_segments=n))
 
-    uMcinv = jnp.linalg.inv(Mc[local_labels])                 # [n_local,d,d]
-    ubc = bc[local_labels]
-    umean_occ = snapshot_mean_occ(seen, size, local_labels)
-    n_clusters = jnp.sum(labels == jnp.arange(n, dtype=labels.dtype))
+        with jax.named_scope("cluster_inverse"):
+            uMcinv = jnp.linalg.inv(Mc[local_labels])         # [n_local,d,d]
+        ubc = bc[local_labels]
+        umean_occ = snapshot_mean_occ(seen, size, local_labels)
+        n_clusters = jnp.sum(labels == jnp.arange(n, dtype=labels.dtype))
     return Stage2Refresh(
         adj=adj, labels=labels, Mc=Mc, bc=bc, size=size, seen=seen,
         uMcinv=uMcinv, ubc=ubc, umean_occ=umean_occ, n_clusters=n_clusters,
@@ -338,7 +365,9 @@ def stage4_rebalance(hyper, occ, umean_occ, u_rounds, c_rounds):
     therefore bounded by ``n * 2 * min(sigma, max_rounds)``, not fixed at
     ``n * 2 * sigma``.
     """
-    delta = ((occ.astype(jnp.float32) - umean_occ) / 2.0).astype(jnp.int32)
-    u_rounds = jnp.clip(u_rounds + delta, 0, hyper.max_rounds)
-    c_rounds = jnp.clip(c_rounds - delta, 0, hyper.max_rounds)
+    with jax.named_scope("stage4"):
+        delta = ((occ.astype(jnp.float32) - umean_occ) / 2.0
+                 ).astype(jnp.int32)
+        u_rounds = jnp.clip(u_rounds + delta, 0, hyper.max_rounds)
+        c_rounds = jnp.clip(c_rounds - delta, 0, hyper.max_rounds)
     return u_rounds, c_rounds
