@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.graph import ops as graph_ops
+from ..kernels.spdinv.ops import spd_inverse
 from .types import ClusterStats, GraphState
 
 
@@ -124,9 +125,9 @@ def cluster_stats(
     Mc = jax.ops.segment_sum(M - eye, labels, num_segments=n) + eye
     bc = jax.ops.segment_sum(b, labels, num_segments=n)
     size = jax.ops.segment_sum(jnp.ones_like(labels), labels, num_segments=n)
-    # one batched solve per stage-2 (not per interaction): cheap and exact.
-    # Rows whose id is not a live label hold garbage; nothing reads them.
-    Mcinv = jnp.linalg.inv(Mc)
+    # one batched inverse per stage-2 (not per interaction).  Rows whose id
+    # is not a live label are the identity, and so are their inverses.
+    Mcinv = spd_inverse(Mc)
     return ClusterStats(
         Mc=Mc,
         Mcinv=Mcinv,

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from . import clustering, linucb
+from ..kernels.spdinv.ops import spd_inverse
 from ..runtime import stages
 from ..runtime.collectives import NullCollectives
 from .backend import BackendConfig, GraphBackend, InteractBackend
@@ -113,7 +114,7 @@ def serving_snapshot(state: DistCLUBState):
 def refresh_gram(state: DistCLUBState) -> DistCLUBState:
     """Recover ``lin.M = inv(lin.Minv)`` (exact up to the accumulated
     Sherman-Morrison fp error) for consumers of the Gram itself."""
-    lin = state.lin._replace(M=jnp.linalg.inv(state.lin.Minv))
+    lin = state.lin._replace(M=spd_inverse(state.lin.Minv))
     return state._replace(lin=lin)
 
 
@@ -138,7 +139,7 @@ def stage2(state: DistCLUBState, hyper: BanditHyper, d: int,
     )
     with jax.named_scope("stage2"):
         with jax.named_scope("cluster_inverse"):
-            Mcinv = jnp.linalg.inv(res.Mc)
+            Mcinv = spd_inverse(res.Mc)
         stats = ClusterStats(Mc=res.Mc, Mcinv=Mcinv, bc=res.bc,
                              size=res.size, seen=res.seen)
         return state._replace(

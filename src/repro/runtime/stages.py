@@ -56,6 +56,7 @@ import jax.numpy as jnp
 
 from ..core import linucb
 from ..core.types import Metrics
+from ..kernels.spdinv.ops import spd_inverse
 
 # ---------------------------------------------------------------------------
 # the shared interaction loop (stage 1, stage 3, DCCB inner loop)
@@ -319,7 +320,7 @@ def stage2_refresh(col, gb, hyper, d, Minv, b, occ, adj) -> Stage2Refresh:
 
         eye = jnp.eye(d, dtype=jnp.float32)
         with jax.named_scope("gram_inverse"):
-            M = jnp.linalg.inv(Minv)
+            M = spd_inverse(Minv)
         with jax.named_scope("cluster_reduce"):
             Mc = col.psum(
                 jax.ops.segment_sum(M - eye, local_labels, num_segments=n)
@@ -332,7 +333,7 @@ def stage2_refresh(col, gb, hyper, d, Minv, b, occ, adj) -> Stage2Refresh:
                                                 num_segments=n))
 
         with jax.named_scope("cluster_inverse"):
-            uMcinv = jnp.linalg.inv(Mc[local_labels])         # [n_local,d,d]
+            uMcinv = spd_inverse(Mc[local_labels])            # [n_local,d,d]
         ubc = bc[local_labels]
         umean_occ = snapshot_mean_occ(seen, size, local_labels)
         n_clusters = jnp.sum(labels == jnp.arange(n, dtype=labels.dtype))

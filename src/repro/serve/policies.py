@@ -56,6 +56,7 @@ from ..core import dccb, distclub, linucb
 from ..core.backend import BackendConfig, InteractBackend
 from ..core.types import BanditHyper, ClusterStats, DistCLUBState, GraphState
 from ..kernels.graph import ops as graph_ops
+from ..kernels.spdinv.ops import spd_inverse
 from ..runtime import stages
 
 from jax.sharding import PartitionSpec as P
@@ -384,7 +385,7 @@ def to_distclub_state(state: ClusteredState, hyper: BanditHyper,
     rebuilt from the per-user rows; M recovered from Minv)."""
     n = state.occ.shape[0]
     Minv = state.Minv.astype(jnp.float32)     # offline record is f32
-    M = jnp.linalg.inv(Minv)
+    M = spd_inverse(Minv)
     lin = linucb.LinUCBState(M=M, Minv=Minv, b=state.b, occ=state.occ)
     eye = jnp.eye(d, dtype=jnp.float32)
     labels = state.labels
@@ -392,7 +393,7 @@ def to_distclub_state(state: ClusteredState, hyper: BanditHyper,
     bc = jax.ops.segment_sum(state.b, labels, num_segments=n)
     size = jax.ops.segment_sum(jnp.ones_like(labels), labels, num_segments=n)
     seen = jax.ops.segment_sum(state.occ, labels, num_segments=n)
-    stats = ClusterStats(Mc=Mc, Mcinv=jnp.linalg.inv(Mc), bc=bc,
+    stats = ClusterStats(Mc=Mc, Mcinv=spd_inverse(Mc), bc=bc,
                          size=size, seen=seen)
     rounds = jnp.full((n,), hyper.sigma, jnp.int32)
     return DistCLUBState(

@@ -1,9 +1,9 @@
-"""The six main-path Pallas kernels compile for a TPU v5e chip.
+"""The seven main-path Pallas kernels compile for a TPU v5e chip.
 
 Each kernel is lowered and compiled (not run) for one chip of a
 described ``v5e:2x2`` topology at the widths ``chip_smoke.py`` drives:
 the paper's synthetic set (20,480 users, d=25 -> 32, K=20 -> 128) for
-choose and the rank-1 fold, and catalog serving (B=1,024 requests over
+choose, the rank-1 fold and the batched SPD inverse, and catalog serving (B=1,024 requests over
 2^20 items at d=32, K_short=64; a stage-2 refresh over 65,536 users) for
 the top-K streams and the graph kernels.  Interpret mode accepts layouts
 the chip's compiler refuses (1-D blocks, batched ``dot_general``,
@@ -26,6 +26,7 @@ from repro.kernels import pad
 from repro.kernels.graph.graph import cc_hop_packed_pallas, prune_packed_pallas
 from repro.kernels.interact.interact import choose_pallas
 from repro.kernels.rank1.rank1 import rank1_update_inv_pallas
+from repro.kernels.spdinv.ops import spd_inverse
 from repro.kernels.topk.topk import topk_pallas, topk_pruned_pallas
 
 # paper phase: padded (n, d, K) of the fused engine
@@ -71,6 +72,9 @@ def _cases(S):
         "rank1_update_inv": lambda: jax.jit(rank1_update_inv_pallas).lower(
             S((N_PAPER, D_PAD, D_PAD)), S((N_PAPER, D_PAD)),
             S((N_PAPER, D_PAD)), S((N_PAPER,)), S((N_PAPER,))),
+        "spd_inverse": lambda: jax.jit(
+            lambda a: spd_inverse(a, use_pallas=True, interpret=False)).lower(
+            S((paper.N_USERS, paper.D_FEAT, paper.D_FEAT))),
         "topk": lambda: jax.jit(
             lambda w, M, o, it, lv: topk_pallas(
                 w, M, o, it, lv, ALPHA, K_SHORT)).lower(
@@ -94,8 +98,9 @@ def _cases(S):
     }
 
 
-@pytest.mark.parametrize("kernel", ["choose", "rank1_update_inv", "topk",
-                                    "topk_pruned", "graph_prune", "cc_hop"])
+@pytest.mark.parametrize("kernel", ["choose", "rank1_update_inv",
+                                    "spd_inverse", "topk", "topk_pruned",
+                                    "graph_prune", "cc_hop"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_compile_cache):
     S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
         shape, dt, sharding=one_chip)
