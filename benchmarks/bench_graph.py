@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from repro.core import backend as backend_mod
 from repro.core import clustering
+from repro.kernels.graph import ops as graph_ops
 
 from .common import emit, timed
 
@@ -49,7 +50,7 @@ KEY = jax.random.PRNGKey(0)
 
 NS = [1024, 4096, 16384, 65536]
 D = 16
-BLOCK_I = 256
+BLOCK_I = graph_ops.BLOCK_I
 # dense needs ~n^2 * 9 transient bytes (adj + i32/f32 [n,n] intermediates):
 # ~2.4 GB at 16384, ~39 GB at 65536 — cap it where the packed path keeps going.
 DENSE_N_CAP = 16384
@@ -115,7 +116,7 @@ def _packed_hop(gb, adj, labels):
 
 def bench_packed(n, d, repeats):
     v, occ, labels = _inputs(n, d)
-    gb = backend_mod.BackendConfig.create().graph(n, block_i=BLOCK_I)
+    gb = backend_mod.BackendConfig.create().graph(n)
     adj = gb.init_adj()
     f_prune = jax.jit(lambda a, v, o: gb.prune(a, v, o, GAMMA))
     f_hop = jax.jit(lambda a, l: _packed_hop(gb, a, l))
@@ -174,8 +175,7 @@ def _interpret_parity(n=150, d=8):
 
     v, occ, labels = _inputs(n, d)
     ref = backend_mod.BackendConfig.create("reference").graph(n)
-    pal = backend_mod.BackendConfig.create("pallas").graph(
-        n, interpret=True, block_i=64, block_j=64)
+    pal = backend_mod.BackendConfig.create("pallas").graph(n, interpret=True)
     adj0 = ref.init_adj()
     a_ref = ref.prune(adj0, v, occ, GAMMA)
     a_pal = pal.prune(adj0, v, occ, GAMMA)

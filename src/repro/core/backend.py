@@ -325,7 +325,8 @@ class InteractBackend(NamedTuple):
 class GraphBackend(NamedTuple):
     """Stage-2 graph engine over the bit-packed adjacency.
 
-    Operates on ``[n_rows, ceil(n_cols/32)]`` uint32 rows (layout:
+    Operates on uint32 rows stored at ``graph_ops.stored_shape(n_rows,
+    n_cols)``, the kernels' padded extents (layout:
     ``repro.kernels.graph.ref``).  ``n_rows == n_cols`` in the single-host
     drivers; the sharded runtime builds one backend per shard with
     ``n_rows = n_local`` and reuses the same kernels on its row shard.
@@ -336,8 +337,6 @@ class GraphBackend(NamedTuple):
     kind: str          # "reference" | "pallas"
     n_rows: int        # adjacency rows held by this caller
     n_cols: int        # global user count (columns)
-    block_i: int       # pallas row tile
-    block_j: int       # pallas column tile (bits; /32 = words)
     row_block: int     # reference-path row blocking (lax.map tile)
     interpret: bool
 
@@ -347,9 +346,13 @@ class GraphBackend(NamedTuple):
         return graph_ops.packed_words(self.n_cols)
 
     def init_adj(self, row_offset: int = 0) -> jnp.ndarray:
-        """Fully-connected packed adjacency minus self edges."""
+        """Fully-connected packed adjacency minus self edges, at its
+        stored shape (``graph_ops.stored_shape``)."""
+        rows, words = graph_ops.stored_shape(self.n_rows, self.n_cols)
         return graph_ops.init_packed_adj(self.n_rows, self.n_cols,
-                                         row_offset=row_offset)
+                                         n_words=words,
+                                         row_offset=row_offset,
+                                         rows_pad=rows)
 
     def pack(self, dense: jnp.ndarray) -> jnp.ndarray:
         return graph_ops.pack_bits(dense, self.words)
@@ -358,9 +361,8 @@ class GraphBackend(NamedTuple):
         return graph_ops.unpack_bits(packed, self.n_cols)
 
     def _opts(self):
-        return dict(use_pallas=self.kind == "pallas", block_i=self.block_i,
-                    block_j=self.block_j, interpret=self.interpret,
-                    row_block=self.row_block)
+        return dict(use_pallas=self.kind == "pallas",
+                    interpret=self.interpret, row_block=self.row_block)
 
     def prune_rows(self, adj, v_i, occ_i, v_j, occ_j, gamma):
         """AND the CLUB keep-mask into the packed rows.  The [n, n] f32
@@ -466,20 +468,22 @@ class RetrievalBackend(NamedTuple):
         describe the bank they were built from, and a stale table's
         bounds are wrong — ``serve`` falls back to :meth:`shortlist`
         when ``clusters.epoch != catalog.epoch``."""
-        tb = tile_bounds(w, Minv, occ, alpha, tile_mu, tile_r, tile_xn,
-                         tile_n)
-        if self.kind == "reference":
-            s, i, skipped, total = topk_ref_pruned(
-                w, Minv, occ, items_sorted, live_sorted, ids_sorted,
-                alpha, self.K_short, tb, row_block=self.row_block,
-                scales=scales_sorted)
-        else:
-            s, i, skipped, total = topk_ops.topk_pruned(
-                w, Minv, occ, items_sorted, live_sorted, ids_sorted,
-                alpha, self.K_short, tb, use_pallas=True,
-                block_users=self.block_users, row_block=self.row_block,
-                interpret=self.interpret, scales=scales_sorted)
-        i = jnp.where(jnp.isfinite(s), i, -1)
+        with jax.named_scope("tile_bounds"):
+            tb = tile_bounds(w, Minv, occ, alpha, tile_mu, tile_r, tile_xn,
+                             tile_n)
+        with jax.named_scope("retrieve"):
+            if self.kind == "reference":
+                s, i, skipped, total = topk_ref_pruned(
+                    w, Minv, occ, items_sorted, live_sorted, ids_sorted,
+                    alpha, self.K_short, tb, row_block=self.row_block,
+                    scales=scales_sorted)
+            else:
+                s, i, skipped, total = topk_ops.topk_pruned(
+                    w, Minv, occ, items_sorted, live_sorted, ids_sorted,
+                    alpha, self.K_short, tb, use_pallas=True,
+                    block_users=self.block_users, row_block=self.row_block,
+                    interpret=self.interpret, scales=scales_sorted)
+            i = jnp.where(jnp.isfinite(s), i, -1)
         return s, i, skipped, total
 
 
@@ -543,7 +547,6 @@ class BackendConfig(NamedTuple):
         )
 
     def graph(self, n_rows: int, n_cols: int | None = None, *,
-              block_i: int = 256, block_j: int = 4096,
               row_block: int = 256,
               interpret: bool | None = None) -> GraphBackend:
         """Stage-2 graph engine for a run's row/column extents.  The
@@ -552,7 +555,7 @@ class BackendConfig(NamedTuple):
         return GraphBackend(
             kind=self.kind, n_rows=n_rows,
             n_cols=n_rows if n_cols is None else n_cols,
-            block_i=block_i, block_j=block_j, row_block=row_block,
+            row_block=row_block,
             interpret=self._interpret(interpret),
         )
 
@@ -610,16 +613,13 @@ def get_graph_backend(
     n_cols: int | None = None,
     kind: str | None = None,
     *,
-    block_i: int = 256,
-    block_j: int = 4096,
     row_block: int = 256,
     interpret: bool | None = None,
 ) -> GraphBackend:
     """Deprecated — use ``BackendConfig.create(kind).graph``."""
     _deprecated("get_graph_backend", "graph(n_rows, n_cols)")
     return BackendConfig.create(kind).graph(
-        n_rows, n_cols, block_i=block_i, block_j=block_j,
-        row_block=row_block, interpret=interpret)
+        n_rows, n_cols, row_block=row_block, interpret=interpret)
 
 
 def get_retrieval_backend(

@@ -20,7 +20,8 @@ Labels live in user-id space (label = smallest user id in the component), so
 all shapes stay static regardless of how many clusters exist.
 
 Representation split: the DistCLUB / CLUB drivers carry the adjacency
-**bit-packed** (``[n, ceil(n/32)] uint32``, see ``repro.kernels.graph``) and
+**bit-packed** (``[n, ceil(n/32)] uint32`` rounded up to the graph
+kernels' blocks, see ``repro.kernels.graph.ops.stored_shape``) and
 run stage 2 through the ``GraphBackend`` engine — pruning only ever clears
 bits, so packing is lossless and AND-monotone, and it cuts graph memory 32x
 (the dense graph cannot even be allocated at the ROADMAP's million-user
@@ -44,8 +45,10 @@ def dense_adj(n_users: int) -> jnp.ndarray:
 
 
 def init_graph(n_users: int) -> GraphState:
-    """Packed fully-connected graph: [n, ceil(n/32)] uint32 rows."""
-    adj = graph_ops.init_packed_adj(n_users, n_users)
+    """Packed fully-connected graph at its stored shape: ``[n,
+    ceil(n/32)]`` uint32 rows rounded up to the graph kernels' blocks,
+    the padding all 0 (``repro.kernels.graph.ops``)."""
+    adj = graph_ops.init_stored_adj(n_users)
     return GraphState(adj=adj, labels=jnp.zeros((n_users,), jnp.int32))
 
 

@@ -104,6 +104,9 @@ class RetrievalMetrics(NamedTuple):
     tiles_total: jnp.ndarray     # [] i32 tile visits possible
     pruned_active: jnp.ndarray   # [] i32 1 = pruned path ran, 0 = stale
     #                                 cluster table, fell back to unpruned
+    fold_passes: jnp.ndarray     # [] i32 feedback-fold passes the
+    #                                 transaction ran (its largest user
+    #                                 multiplicity; 0 with no fold)
 
     def skip_ratio(self) -> float:
         """Host-side tiles_skipped / tiles_total (0 when fallen back)."""

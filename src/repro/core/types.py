@@ -45,8 +45,10 @@ class GraphState(NamedTuple):
 
     adj      : [n, ceil(n/32)] uint32 — bit-packed rows, LSB-first (bit
                ``j % 32`` of word ``j // 32`` = edge (i, j); layout in
-               ``repro.kernels.graph.ref``).  Row-sharded in the
-               distributed runtime.  Edges are only ever pruned, so the
+               ``repro.kernels.graph.ref``), stored with rows and words
+               rounded up to the graph kernels' blocks, the padding 0
+               (``repro.kernels.graph.ops.stored_shape``).  Row-sharded in
+               the distributed runtime.  Edges are only ever pruned, so the
                packing is AND-monotone and 32x smaller than dense bool.
     labels   : [n] i32      cluster label = min user-id in the component
     """

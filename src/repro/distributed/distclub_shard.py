@@ -33,6 +33,7 @@ carried — they are epoch transients of stage 2.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -64,7 +65,8 @@ class ShardedDistCLUB(NamedTuple):
     Minv: jnp.ndarray     # [n, d, d]   sharded dim0
     b: jnp.ndarray        # [n, d]      sharded dim0
     occ: jnp.ndarray      # [n]         sharded dim0
-    adj: jnp.ndarray      # [n, ceil(n/32)] uint32 bit-packed, sharded rows
+    adj: jnp.ndarray      # [S rows, words] uint32 bit-packed, sharded rows
+    #                       (each shard's block at stored_shape(n_local, n))
     labels: jnp.ndarray   # [n]         replicated (n i32 — cheap)
     u_rounds: jnp.ndarray  # [n] i32    sharded dim0
     c_rounds: jnp.ndarray  # [n] i32    sharded dim0
@@ -87,13 +89,14 @@ def state_specs(axes: tuple[str, ...]) -> ShardedDistCLUB:
     )
 
 
-def init_state(n: int, d: int, hyper: BanditHyper) -> ShardedDistCLUB:
+def init_state(n: int, d: int, hyper: BanditHyper,
+               shards: int = 1) -> ShardedDistCLUB:
     eye = jnp.eye(d, dtype=jnp.float32) + jnp.zeros((n, d, d), jnp.float32)
     return ShardedDistCLUB(
         Minv=eye,
         b=jnp.zeros((n, d), jnp.float32),
         occ=jnp.zeros((n,), jnp.int32),
-        adj=graph_ops.init_packed_adj(n, n),
+        adj=graph_ops.init_stored_adj(n, shards),
         labels=jnp.zeros((n,), jnp.int32),
         u_rounds=jnp.full((n,), hyper.sigma, jnp.int32),
         c_rounds=jnp.full((n,), hyper.sigma, jnp.int32),
@@ -189,7 +192,8 @@ def make_runtime(mesh: Mesh, axes: tuple[str, ...], n: int, d: int,
 
     def init_fn(key):
         del key
-        return jax.device_put(init_state(n, d, hyper), shardings)
+        shards = math.prod(mesh.shape[a] for a in axes)
+        return jax.device_put(init_state(n, d, hyper, shards), shardings)
 
     epoch_jit = jax.jit(
         epoch,

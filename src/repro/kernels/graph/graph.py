@@ -28,10 +28,14 @@ bit ``b`` of every word of the tile, packing is 32 aligned slices shifted
 and OR-ed, and unpacking is the same in reverse — no reshape.  The
 column-side vectors (``v_j``, its squared norms, ``cb_j``, ``labels_j``)
 are permuted in XLA next to the call, an O(n d) copy against the O(n^2/8)
-sweep; the packed adjacency itself keeps its layout.  The kernels see the
-adjacency as int32 (bit-identical; Mosaic has no unsigned reductions),
-per-row vectors as ``[R, 1]`` columns, per-column vectors as ``[1, C]``
-rows, and ``gamma`` in SMEM.
+sweep; the packed adjacency itself keeps its layout.  The kernels take
+the adjacency as it is stored, uint32, and use only bitwise operations,
+shifts and equality on it (no unsigned reduction), so no converted copy
+of the graph exists; per-row vectors are ``[R, 1]`` columns, per-column
+vectors ``[1, C]`` rows, and ``gamma`` sits in SMEM.  The prune writes its
+result over its input (``input_output_aliases``): each output tile is the
+input tile of the same grid step, so at most one graph-sized buffer is
+live however large the graph.
 
 Both kernels are shape-polymorphic over rows vs columns, so the sharded
 runtime reuses them unchanged on ``[n_local, n]`` row shards inside
@@ -72,9 +76,10 @@ def _prune_kernel(vi_ref, vj_ref, ni_ref, nj_ref, cbi_ref, cbj_ref,
     keep = dist < gamma_ref[0] * (cbi_ref[...] + cbj_ref[...])
 
     bi, wb = adj_ref.shape
-    words = jnp.zeros((bi, wb), jnp.int32)
+    words = jnp.zeros((bi, wb), jnp.uint32)
     for b in range(32):
-        words = words | (keep[:, b * wb:(b + 1) * wb].astype(jnp.int32) << b)
+        words = words | (keep[:, b * wb:(b + 1) * wb].astype(jnp.uint32)
+                         << b)
     out_ref[...] = adj_ref[...] & words
 
 
@@ -112,25 +117,25 @@ def prune_packed_pallas(
             pl.BlockSpec((block_i, wb), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((block_i, wb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, Wp), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((R, Wp), jnp.uint32),
+        input_output_aliases={7: 0},
         interpret=interpret,
         name="graph_prune",
     )(v_i, _bit_major(v_j, block_j),
       jnp.sum(v_i * v_i, axis=-1).reshape(R, 1),
       _bit_major(jnp.sum(v_j * v_j, axis=-1), block_j).reshape(1, C),
       cb_i.reshape(R, 1), _bit_major(cb_j, block_j).reshape(1, C),
-      jnp.asarray(gamma, jnp.float32).reshape(1),
-      jax.lax.bitcast_convert_type(packed, jnp.int32))
-    return jax.lax.bitcast_convert_type(out, jnp.uint32)
+      jnp.asarray(gamma, jnp.float32).reshape(1), packed)
+    return out
 
 
 def _cc_hop_kernel(adj_ref, lself_ref, lj_ref, out_ref):
     j = pl.program_id(1)
-    adj = adj_ref[...]                # [Bi, Wb] i32 (packed bits)
+    adj = adj_ref[...]                # [Bi, Wb] u32 (packed bits)
     bi, wb = adj.shape
     m = jnp.full((bi, wb), BIG_LABEL, jnp.int32)
     for b in range(32):
-        bit = ((adj >> b) & 1) > 0
+        bit = ((adj >> b) & 1) != 0
         # lane slice b of the bit-major labels: the columns of bit b
         lab = jnp.broadcast_to(lj_ref[:, b * wb:(b + 1) * wb], (bi, wb))
         m = jnp.minimum(m, jnp.where(bit, lab, BIG_LABEL))
@@ -173,7 +178,7 @@ def cc_hop_packed_pallas(
         out_shape=jax.ShapeDtypeStruct((R, 1), jnp.int32),
         interpret=interpret,
         name="cc_hop",
-    )(jax.lax.bitcast_convert_type(packed, jnp.int32),
+    )(packed,
       labels_self.reshape(R, 1),
       _bit_major(labels_j, block_j).reshape(1, C))
     return out[:, 0]

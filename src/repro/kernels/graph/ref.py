@@ -58,8 +58,10 @@ def unpack_bits(packed: jnp.ndarray, n_cols: int) -> jnp.ndarray:
 
 
 def init_packed_adj(n_rows: int, n_cols: int, n_words: int | None = None,
-                    row_offset: int = 0) -> jnp.ndarray:
-    """Fully-connected packed adjacency minus self edges, [n_rows, W] u32.
+                    row_offset: int = 0,
+                    rows_pad: int | None = None) -> jnp.ndarray:
+    """Fully-connected packed adjacency minus self edges, [n_rows, W] u32
+    (``[rows_pad, W]`` with ``rows_pad``: the rows past ``n_rows`` are 0).
 
     Built arithmetically (no [n, n] bool intermediate): full words below
     ``n_cols`` are 0xFFFFFFFF, the boundary word keeps its low
@@ -73,7 +75,11 @@ def init_packed_adj(n_rows: int, n_cols: int, n_words: int | None = None,
     partial = (jnp.uint32(1) << jnp.minimum(rem, 31).astype(jnp.uint32)
                ) - jnp.uint32(1)
     word = jnp.where(rem >= 32, full, partial)
-    adj = jnp.broadcast_to(word, (n_rows, W))
+    if rows_pad is None or rows_pad == n_rows:
+        adj = jnp.broadcast_to(word, (n_rows, W))
+    else:
+        real = jnp.arange(rows_pad, dtype=jnp.int32)[:, None] < n_rows
+        adj = jnp.where(real, word[None, :], jnp.uint32(0))
     i = jnp.arange(n_rows, dtype=jnp.int32) + row_offset
     dw, db = i // 32, (i % 32).astype(jnp.uint32)
     rows = jnp.arange(n_rows)

@@ -4,7 +4,8 @@ A policy is everything an :class:`~repro.serve.session.OnlineBandit`
 session needs to turn a request batch into choices and fold feedback
 back — four hooks over a policy-specific state pytree:
 
-  init()                          -> state        (global shapes)
+  init(shards=1)                  -> state        (global shapes, laid
+                                     out for ``shards`` row shards)
   gather_score(state, idx)        -> (w, minv_eff, occ) rows for the
                                      fused choose, gathered per request
   apply_pass(state, idx, x, r, live, be)
@@ -51,6 +52,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..core import dccb, distclub, linucb
 from ..core.backend import BackendConfig, InteractBackend
@@ -87,13 +89,25 @@ def _scatter_rows(array, tgt, rows):
     return array.at[tgt].set(rows, mode="drop")
 
 
+def _row_major(array):
+    """``array`` laid out row-major, users on the major dimension.  A TPU
+    stores a narrow ``[n, d, d]`` table with its users on the lanes, where
+    a row gather works but a row scatter does not: XLA would copy the
+    whole table into row-major order for every pass's scatter."""
+    return with_layout_constraint(
+        array, Layout(major_to_minor=tuple(range(array.ndim))))
+
+
 def _rank1_pass(Minv, b, occ, idx, x, r, live, be):
     """One fused masked Sherman-Morrison pass over gathered rows,
     scattered back for the live (distinct-user) rows only — the shared
-    feedback body of every LinUCB-statistics policy."""
+    feedback body of every LinUCB-statistics policy.  The tables stay
+    row-major across passes (``_row_major``)."""
+    Minv, b = _row_major(Minv), _row_major(b)
     Minv2, b2 = be.update_inv(Minv[idx], b[idx], x, r, live)
     tgt = jnp.where(live, idx, occ.shape[0])
-    return (_scatter_rows(Minv, tgt, Minv2), _scatter_rows(b, tgt, b2),
+    return (_row_major(_scatter_rows(Minv, tgt, Minv2)),
+            _row_major(_scatter_rows(b, tgt, b2)),
             occ.at[tgt].add(1, mode="drop"))
 
 
@@ -110,7 +124,8 @@ class ClusteredState(NamedTuple):
     Minv: jnp.ndarray         # [n_local, d, d]
     b: jnp.ndarray            # [n_local, d]
     occ: jnp.ndarray          # [n_local] i32
-    adj: jnp.ndarray          # [n_local, ceil(n/32)] uint32 packed rows
+    adj: jnp.ndarray          # [n_local, ceil(n/32)] uint32 packed rows,
+    #                           at graph_ops.stored_shape(n_local, n)
     labels: jnp.ndarray       # [n] i32 replicated
     uMcinv: jnp.ndarray       # [n_local, d, d]  frozen cluster snapshot
     ubc: jnp.ndarray          # [n_local, d]
@@ -135,7 +150,9 @@ class ClusteredPolicy(NamedTuple):
     def has_refresh(self) -> bool:
         return True
 
-    def init(self) -> ClusteredState:
+    def init(self, shards: int = 1) -> ClusteredState:
+        """The fresh state; ``shards`` row shards lay the graph out a
+        block per shard (``graph_ops.init_stored_adj``)."""
         n, d = self.cfg.n_users, self.cfg.d
         # HBM-dominant [n, d, d] state lives in the session's Precision
         # state dtype (f32 default -> these astype calls are no-ops)
@@ -146,7 +163,7 @@ class ClusteredPolicy(NamedTuple):
             Minv=eye,
             b=jnp.zeros((n, d), jnp.float32),
             occ=jnp.zeros((n,), jnp.int32),
-            adj=graph_ops.init_packed_adj(n, n),
+            adj=graph_ops.init_stored_adj(n, shards),
             labels=jnp.zeros((n,), jnp.int32),   # one big cluster initially
             uMcinv=eye,
             ubc=jnp.zeros((n, d), jnp.float32),
@@ -229,7 +246,8 @@ class LinUCBPolicy(NamedTuple):
     def has_refresh(self) -> bool:
         return False
 
-    def init(self) -> LinUCBServeState:
+    def init(self, shards: int = 1) -> LinUCBServeState:
+        del shards                    # no graph: rows shard as they are
         n, d = self.cfg.n_users, self.cfg.d
         sdt = self.cfg.engine.precision.jnp_state
         eye = jnp.broadcast_to(jnp.eye(d, dtype=jnp.float32),
@@ -297,7 +315,8 @@ class DCCBPolicy(NamedTuple):
     def L(self) -> int:
         return self.cfg.hyper.buffer_size
 
-    def init(self) -> DCCBServeState:
+    def init(self, shards: int = 1) -> DCCBServeState:
+        del shards                    # single-host only
         return DCCBServeState(
             core=dccb.init_state(self.cfg.n_users, self.cfg.d, self.L),
             since_refresh=jnp.zeros((), jnp.int32),

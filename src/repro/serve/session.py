@@ -84,6 +84,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from ..core import catalog as catalog_mod
@@ -173,19 +174,44 @@ def _choose(policy, col, state, user_ids, contexts):
     x = col.psum(jnp.where(own[:, None], x, jnp.zeros_like(x)))
     return choice, x, (idx, own, valid, be)
 
+def _state_layouts(state):
+    """The stored layout of each leaf of ``state`` (its ``major_to_minor``
+    order), or ``None`` for a leaf that carries none, such as a tracer."""
+    def one(leaf):
+        layout = getattr(getattr(leaf, "format", None), "layout", None)
+        return getattr(layout, "major_to_minor", None)
+    return tuple(one(leaf) for leaf in jax.tree.leaves(state))
+
+
 def _fold_feedback(policy, state, idx, own, valid, be, user_ids, x,
-                   realized):
+                   realized, layouts=None):
     """Duplicate-safe feedback fold: one fused masked pass per occurrence
     rank (live rows of a pass are distinct users -> the pass is exact;
-    distinct-user batches take exactly one pass)."""
-    ranks = _occurrence_ranks(user_ids)
-    n_passes = jnp.max(jnp.where(valid, ranks, -1)) + 1
+    distinct-user batches take exactly one pass).  Returns ``(state,
+    n_passes)``: the passes run, the largest multiplicity of any valid
+    user in the batch.
 
-    def one_pass(k, st):
-        live = own & (ranks == k)
-        return policy.apply_pass(st, idx, x, realized, live, be)
+    The passes keep the tables they scatter into row-major; ``layouts``
+    (``_state_layouts`` of the stored state) puts each table back in its
+    stored layout inside this scope, so the transaction's output needs
+    no further copy."""
+    with jax.named_scope("fold"):
+        ranks = _occurrence_ranks(user_ids)
+        n_passes = jnp.max(jnp.where(valid, ranks, -1)) + 1
 
-    return jax.lax.fori_loop(0, n_passes, one_pass, state)
+        def one_pass(k, st):
+            live = own & (ranks == k)
+            return policy.apply_pass(st, idx, x, realized, live, be)
+
+        state = jax.lax.fori_loop(0, n_passes, one_pass, state)
+        if layouts is not None:
+            leaves, tree = jax.tree.flatten(state)
+            state = tree.unflatten([
+                leaf if order is None or leaf.ndim < 2
+                else with_layout_constraint(leaf,
+                                            Layout(major_to_minor=order))
+                for leaf, order in zip(leaves, layouts)])
+        return state, n_passes
 
 
 def _schedule_refresh(policy, col, state, n_new, key):
@@ -205,19 +231,21 @@ def _schedule_refresh(policy, col, state, n_new, key):
                                col.psum(jnp.sum(policy.occ_of(state))))
 
     def fire(st):
-        st = policy.refresh(col, st, k_ref)
+        with jax.named_scope("refresh"):
+            st = policy.refresh(col, st, k_ref)
         return st._replace(since_refresh=jnp.zeros((), jnp.int32))
 
     return jax.lax.cond(since >= every, fire, lambda st: st, state)
 
 
 def _apply_feedback(policy, col, state, key, idx, own, valid, be,
-                    user_ids, x, rewards):
+                    user_ids, x, rewards, layouts=None):
     """The shared transaction tail of both step bodies: fold the reward
-    4-tuple, run the refresh schedule, reduce the batch metrics."""
+    4-tuple, run the refresh schedule, reduce the batch metrics.  Returns
+    ``(state, metrics, fold passes)``."""
     realized, expected, best, rand = rewards
-    state = _fold_feedback(policy, state, idx, own, valid, be, user_ids,
-                           x, realized)
+    state, n_passes = _fold_feedback(policy, state, idx, own, valid, be,
+                                     user_ids, x, realized, layouts)
     n_new = jnp.sum(valid.astype(jnp.int32))
     state = _schedule_refresh(policy, col, state, n_new, key)
     vm = valid.astype(realized.dtype)
@@ -227,15 +255,15 @@ def _apply_feedback(policy, col, state, key, idx, own, valid, be,
         rand_reward=jnp.sum(rand * vm),
         interactions=n_new,
     )
-    return state, metrics
+    return state, metrics, n_passes
 
 
 def _step_body(policy, reward_fn, col, state, key, user_ids, contexts):
     choice, x, (idx, own, valid, be) = _choose(policy, col, state,
                                                user_ids, contexts)
     rewards = _normalize_rewards(reward_fn(key, user_ids, contexts, choice))
-    state, metrics = _apply_feedback(policy, col, state, key, idx, own,
-                                     valid, be, user_ids, x, rewards)
+    state, metrics, _ = _apply_feedback(policy, col, state, key, idx, own,
+                                        valid, be, user_ids, x, rewards)
     return state, choice, metrics
 
 
@@ -243,8 +271,8 @@ def _observe_body(policy, col, state, key, user_ids, contexts, choices,
                   rewards):
     idx, own, valid, be = _request_masks(policy, col, state, user_ids)
     x = jnp.take_along_axis(contexts, choices[:, None, None], axis=1)[:, 0]
-    state = _fold_feedback(policy, state, idx, own, valid, be, user_ids,
-                           x, rewards)
+    state, _ = _fold_feedback(policy, state, idx, own, valid, be, user_ids,
+                              x, rewards)
     n_new = jnp.sum(valid.astype(jnp.int32))
     return _schedule_refresh(policy, col, state, n_new, key)
 
@@ -290,11 +318,12 @@ def _catalog_choose(policy, rb, col, state, user_ids, catalog,
     """
     cfg = policy.cfg
     idx, own, valid, be = _request_masks(policy, col, state, user_ids)
-    w, minv_eff, occ_rows = policy.gather_score(state, idx)
-    # replicate the request rows: exactly one shard owns each valid user
-    w = col.psum(jnp.where(own[:, None], w, 0.0))
-    minv_eff = col.psum(jnp.where(own[:, None, None], minv_eff, 0.0))
-    occ_rows = col.psum(jnp.where(own, occ_rows, 0))
+    with jax.named_scope("gather_score"):
+        w, minv_eff, occ_rows = policy.gather_score(state, idx)
+        # replicate the request rows: exactly one shard owns each user
+        w = col.psum(jnp.where(own[:, None], w, 0.0))
+        minv_eff = col.psum(jnp.where(own[:, None, None], minv_eff, 0.0))
+        occ_rows = col.psum(jnp.where(own, occ_rows, 0))
 
     bank = catalog.serving            # the ACTIVE double-buffer bank
     n_local_items = bank.live.shape[0]
@@ -303,9 +332,10 @@ def _catalog_choose(policy, rb, col, state, user_ids, catalog,
     # f32/bf16 banks upcast in VMEM without scales (trace-time branch)
     scales = bank.scale if bank.emb.dtype == jnp.int8 else None
     if clusters is None:
-        sc, ids = rb.shortlist(w, minv_eff, occ_rows, bank.emb, bank.live,
-                               cfg.hyper.alpha, row0_items=row0_items,
-                               scales=scales)
+        with jax.named_scope("retrieve"):
+            sc, ids = rb.shortlist(w, minv_eff, occ_rows, bank.emb,
+                                   bank.live, cfg.hyper.alpha,
+                                   row0_items=row0_items, scales=scales)
         rmet = None
     else:
         shard_tabs = itemclub_mod.shard_slice(clusters, col.axis_index(),
@@ -322,9 +352,10 @@ def _catalog_choose(policy, rb, col, state, user_ids, catalog,
                                        scales_sorted=ss)
 
         def _unpruned(_):
-            s, i = rb.shortlist(w, minv_eff, occ_rows, bank.emb,
-                                bank.live, cfg.hyper.alpha,
-                                row0_items=row0_items, scales=scales)
+            with jax.named_scope("retrieve"):
+                s, i = rb.shortlist(w, minv_eff, occ_rows, bank.emb,
+                                    bank.live, cfg.hyper.alpha,
+                                    row0_items=row0_items, scales=scales)
             z = jnp.zeros((), jnp.int32)
             return s, i, z, z
 
@@ -334,45 +365,56 @@ def _catalog_choose(policy, rb, col, state, user_ids, catalog,
             tiles_skipped=col.psum(skipped),
             tiles_total=col.psum(total),
             pruned_active=fresh.astype(jnp.int32),
+            fold_passes=jnp.zeros((), jnp.int32),
         )
-    sc_all = col.all_gather(sc[None])           # [S, B, K_short]
-    id_all = col.all_gather(ids[None])
-    B = user_ids.shape[0]
-    sc_flat = jnp.moveaxis(sc_all, 0, 1).reshape(B, -1)
-    id_flat = jnp.moveaxis(id_all, 0, 1).reshape(B, -1)
-    # merge with the kernel's OWN selection routine, so the merged order
-    # is the kernel's order by construction (not a re-implementation
-    # that could diverge on e.g. signed-zero ties)
-    top_s, top_i = select_topk(sc_flat, id_flat, rb.K_short)
-    top_i = jnp.where(jnp.isfinite(top_s), top_i, top_i[:, :1])
+    with jax.named_scope("retrieve"):
+        sc_all = col.all_gather(sc[None])           # [S, B, K_short]
+        id_all = col.all_gather(ids[None])
+        B = user_ids.shape[0]
+        sc_flat = jnp.moveaxis(sc_all, 0, 1).reshape(B, -1)
+        id_flat = jnp.moveaxis(id_all, 0, 1).reshape(B, -1)
+        # merge with the kernel's OWN selection routine, so the merged
+        # order is the kernel's order by construction (not a
+        # re-implementation that could diverge on e.g. signed-zero ties)
+        top_s, top_i = select_topk(sc_flat, id_flat, rb.K_short)
+        top_i = jnp.where(jnp.isfinite(top_s), top_i, top_i[:, :1])
 
-    loc = top_i - row0_items
-    ok = (loc >= 0) & (loc < n_local_items)
-    g = jnp.clip(loc, 0, n_local_items - 1)
-    # dequantize the gathered shortlist rows before the f32 psum — the
-    # slate the fused choose (and the reward_fn) sees is always f32
-    rows = bank.emb[g].astype(jnp.float32)
-    if scales is not None:
-        rows = rows * bank.scale[g][..., None]
-    ctx = col.psum(jnp.where(ok[..., None], rows, 0.0))   # [B, K_short, d]
+        loc = top_i - row0_items
+        ok = (loc >= 0) & (loc < n_local_items)
+        g = jnp.clip(loc, 0, n_local_items - 1)
+        # dequantize the gathered shortlist rows before the f32 psum —
+        # the slate the fused choose (and the reward_fn) sees is f32
+        rows = bank.emb[g].astype(jnp.float32)
+        if scales is not None:
+            rows = rows * bank.scale[g][..., None]
+        ctx = col.psum(jnp.where(ok[..., None], rows, 0.0))  # [B, K, d]
 
-    be_s = be.with_candidates(rb.K_short)
-    x, slot = be_s.choose(w, minv_eff, ctx, occ_rows, cfg.hyper.alpha)
-    item = jnp.take_along_axis(top_i, slot[:, None], axis=1)[:, 0]
-    item = jnp.where(valid, item, -1)
+    with jax.named_scope("choose"):
+        be_s = be.with_candidates(rb.K_short)
+        x, slot = be_s.choose(w, minv_eff, ctx, occ_rows, cfg.hyper.alpha)
+        item = jnp.take_along_axis(top_i, slot[:, None], axis=1)[:, 0]
+        item = jnp.where(valid, item, -1)
     return item, slot, ctx, x, (idx, own, valid, be), rmet
 
 
-def _catalog_step_body(policy, rb, reward_fn, col, state, key, user_ids,
-                       catalog, clusters=None):
-    item, slot, ctx, x, (idx, own, valid, be), rmet = _catalog_choose(
-        policy, rb, col, state, user_ids, catalog, clusters)
-    rewards = _normalize_rewards(reward_fn(key, user_ids, ctx, slot))
-    state, metrics = _apply_feedback(policy, col, state, key, idx, own,
-                                     valid, be, user_ids, x, rewards)
+def _catalog_step_body(policy, rb, reward_fn, layouts, col, state, key,
+                       user_ids, catalog, clusters=None):
+    """The catalog transaction, under the scope ``serve``; its parts name
+    ``gather_score``, ``tile_bounds``, ``retrieve``, ``choose``,
+    ``env_rewards``, ``fold`` and ``refresh`` (which holds stage 2's own
+    scopes), so a device profile charges every operation to one."""
+    with jax.named_scope("serve"):
+        item, slot, ctx, x, (idx, own, valid, be), rmet = _catalog_choose(
+            policy, rb, col, state, user_ids, catalog, clusters)
+        with jax.named_scope("env_rewards"):
+            rewards = _normalize_rewards(reward_fn(key, user_ids, ctx,
+                                                   slot))
+        state, metrics, n_passes = _apply_feedback(
+            policy, col, state, key, idx, own, valid, be, user_ids, x,
+            rewards, layouts)
     if clusters is None:
         return state, item, metrics
-    return state, item, metrics, rmet
+    return state, item, metrics, rmet._replace(fold_passes=n_passes)
 
 
 # ---------------------------------------------------------------------------
@@ -411,8 +453,8 @@ def _observe_delayed_body(policy, col, state, pend, key, decision_ids,
     are quarantined by the match (freed + counted, never folded)."""
     pend, uids, x = pending_mod.match(pend, decision_ids, stale=stale)
     idx, own, valid, be = _request_masks(policy, col, state, uids)
-    state = _fold_feedback(policy, state, idx, own, valid, be, uids, x,
-                           rewards)
+    state, _ = _fold_feedback(policy, state, idx, own, valid, be, uids, x,
+                              rewards)
     n_new = jnp.sum(valid.astype(jnp.int32))
     state = _schedule_refresh(policy, col, state, n_new, key)
     return state, pend
@@ -514,13 +556,16 @@ def _observe_fn(policy, mesh, axes):
 
 
 def _bind_catalog_tx(policy, body, mesh, axes, n_plain, out_specs,
-                     tail_specs=()):
+                     tail_specs=(), donate=False):
     """Like ``_bind_tx`` but the trailing arguments after the ``n_plain``
     replicated request inputs are a Catalog sharded on the ITEM axis over
     the same mesh axes the user state shards on, then any ``tail_specs``
-    extras (e.g. a replicated ``ItemClusters`` on the pruned path)."""
+    extras (e.g. a replicated ``ItemClusters`` on the pruned path).  With
+    ``donate`` the state's buffers are handed to the transaction."""
+    donated = (0,) if donate else ()
     if mesh is None:
-        return jax.jit(functools.partial(body, _NULL))
+        return jax.jit(functools.partial(body, _NULL),
+                       donate_argnums=donated)
     col = lax_collectives(mesh, axes)
     bound = functools.partial(body, col)
     in_specs = ((policy.state_specs(axes),)
@@ -534,15 +579,17 @@ def _bind_catalog_tx(policy, body, mesh, axes, n_plain, out_specs,
         )
         return mapped(state, *args)
 
-    return jax.jit(wrap)
+    return jax.jit(wrap, donate_argnums=donated)
 
 
-_RMET_SPECS = itemclub_mod.RetrievalMetrics(P(), P(), P())
+_RMET_SPECS = itemclub_mod.RetrievalMetrics(P(), P(), P(), P())
 
 
 @functools.lru_cache(maxsize=64)
-def _catalog_step_fn(policy, rb, reward_fn, mesh, axes, pruned=False):
-    body = functools.partial(_catalog_step_body, policy, rb, reward_fn)
+def _catalog_step_fn(policy, rb, reward_fn, mesh, axes, pruned=False,
+                     donate=False, layouts=None):
+    body = functools.partial(_catalog_step_body, policy, rb, reward_fn,
+                             layouts)
     out = ((policy.state_specs(axes) if mesh is not None else None),
            P(), Metrics(P(), P(), P(), P()))
     if pruned:
@@ -550,7 +597,8 @@ def _catalog_step_fn(policy, rb, reward_fn, mesh, axes, pruned=False):
     return _bind_catalog_tx(policy, body, mesh, axes, n_plain=2,
                             out_specs=out,
                             tail_specs=((itemclub_mod.specs(),)
-                                        if pruned else ()))
+                                        if pruned else ()),
+                            donate=donate)
 
 
 @functools.lru_cache(maxsize=64)
@@ -713,7 +761,7 @@ class OnlineBandit:
             raise ValueError(
                 f"the {shards}-way mesh must evenly divide n_users={n_users}")
         state = jax.device_put(
-            p.init(), named_shardings(mesh, p.state_specs(axes)))
+            p.init(shards), named_shardings(mesh, p.state_specs(axes)))
         pend = (pending_mod.init(pending_capacity, d)
                 if pending_capacity > 0 else None)
         return cls(policy=p, state=state, mesh=mesh, axes=axes,
@@ -798,9 +846,10 @@ class OnlineBandit:
         return recommend(self, user_ids, contexts)
 
     def step_catalog(self, key, user_ids, catalog, reward_fn, *,
-                     k_short: int = 64, clusters=None):
+                     k_short: int = 64, clusters=None, donate=False):
         return step_catalog(self, key, user_ids, catalog, reward_fn,
-                            k_short=k_short, clusters=clusters)
+                            k_short=k_short, clusters=clusters,
+                            donate=donate)
 
     def recommend_catalog(self, user_ids, catalog, *, k_short: int = 64,
                           clusters=None):
@@ -887,7 +936,8 @@ def _retrieval_engine(session: OnlineBandit, k_short: int):
 
 
 def step_catalog(session: OnlineBandit, key, user_ids, catalog,
-                 reward_fn: Callable, *, k_short: int = 64, clusters=None):
+                 reward_fn: Callable, *, k_short: int = 64, clusters=None,
+                 donate: bool = False):
     """One serving transaction against a persistent catalog.
 
     Like :func:`step`, but the slate is not supplied by the caller — it
@@ -915,10 +965,16 @@ def step_catalog(session: OnlineBandit, key, user_ids, catalog,
     ``pruned_active``).  The cluster tables are replicated — pass them
     as-is on a sharded session (``capacity % (tile_items * shards)``
     must be 0).
+
+    ``donate`` hands the session's state buffers to the transaction, which
+    updates them in place: no second copy of the state is allocated, and
+    the state of the ``session`` passed in must not be read afterwards
+    (copy out first what is to be kept).
     """
     rb = _retrieval_engine(session, k_short)
     fn = _catalog_step_fn(session.policy, rb, reward_fn, session.mesh,
-                          session.axes, clusters is not None)
+                          session.axes, clusters is not None, donate,
+                          _state_layouts(session.state))
     if clusters is None:
         state, item_ids, metrics = fn(session.state, key, user_ids,
                                       catalog)

@@ -80,15 +80,15 @@ def test_prune_packed_matches_dense_oracle(n, d):
     dense0 = random_sym_adj(rng, n, 0.7)
     want = clustering.prune_edges(jnp.asarray(dense0), v, occ, gamma=1.2)
 
-    packed = graph_ops.pack_bits(jnp.asarray(dense0))
+    packed = _stored(graph_ops.pack_bits(jnp.asarray(dense0)), n)
     cb = clustering.cb_width(occ)
     for kwargs in (
         dict(use_pallas=False, row_block=16),
-        dict(use_pallas=True, interpret=True, block_i=16, block_j=32),
-        dict(use_pallas=True, interpret=True, block_i=8, block_j=64),
+        dict(use_pallas=True, interpret=True),
     ):
-        got = graph_ops.unpack_bits(
-            graph_ops.prune_packed(packed, v, cb, v, cb, 1.2, **kwargs), n)
+        got = graph_ops.unpack_bits(graph_ops.user_rows(
+            graph_ops.prune_packed(packed, v, cb, v, cb, 1.2, **kwargs), n),
+            n)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=str(kwargs))
 
@@ -119,11 +119,10 @@ def test_cc_packed_matches_dense(maker, n):
              "chain": lambda: chain_adj(n),
              "empty": lambda: np.zeros((n, n), bool)}[maker]()
     want = clustering.connected_components(jnp.asarray(dense))
-    packed = graph_ops.pack_bits(jnp.asarray(dense))
+    packed = _stored(graph_ops.pack_bits(jnp.asarray(dense)), n)
     gb_ref = backend.BackendConfig.create("reference").graph(n,
                                                              row_block=16)
-    gb_pal = backend.BackendConfig.create("pallas").graph(
-        n, interpret=True, block_i=16, block_j=64)
+    gb_pal = backend.BackendConfig.create("pallas").graph(n, interpret=True)
     np.testing.assert_array_equal(np.asarray(gb_ref.cc(packed)),
                                   np.asarray(want))
     np.testing.assert_array_equal(np.asarray(gb_pal.cc(packed)),
@@ -144,8 +143,7 @@ def test_cc_hop_bipartite_rows():
 
     packed_rows = graph_ops.pack_bits(rows)
     for kwargs in (dict(use_pallas=False, row_block=8),
-                   dict(use_pallas=True, interpret=True,
-                        block_i=8, block_j=32)):
+                   dict(use_pallas=True, interpret=True)):
         got = graph_ops.cc_hop_packed(
             packed_rows, labels[off:off + n_local], labels, **kwargs)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
@@ -173,8 +171,12 @@ def test_graph_backend_pack_roundtrip_and_init():
     dense = clustering.dense_adj(45)
     np.testing.assert_array_equal(np.asarray(gb.unpack(gb.pack(dense))),
                                   np.asarray(dense))
-    np.testing.assert_array_equal(np.asarray(gb.unpack(gb.init_adj())),
+    adj = gb.init_adj()
+    # stored at the kernels' padded extents, the padding all zero
+    assert adj.shape == graph_ops.stored_shape(45, 45) == (48, 2)
+    np.testing.assert_array_equal(np.asarray(gb.unpack(adj[:45])),
                                   np.asarray(dense))
+    assert not np.asarray(adj[45:]).any()
 
 
 # ---- end-to-end ------------------------------------------------------------
@@ -192,8 +194,7 @@ def test_distclub_stage2_reference_vs_pallas_interpret():
     pal_i = backend.BackendConfig.create("pallas").interact(
         N, D, K, interpret=True)
     ref_g = backend.BackendConfig.create("reference").graph(N)
-    pal_g = backend.BackendConfig.create("pallas").graph(
-        N, interpret=True, block_i=8, block_j=32)
+    pal_g = backend.BackendConfig.create("pallas").graph(N, interpret=True)
 
     s_r, m_r, c_r = distclub.run(ops, jax.random.PRNGKey(1), hyper,
                                  n_epochs=2, d=D, backend=ref_i, graph=ref_g)
@@ -215,3 +216,96 @@ def test_distclub_state_carries_packed_graph():
     state = distclub.init_state(N, D, BanditHyper())
     assert state.graph.adj.shape == (N, (N + 31) // 32)
     assert state.graph.adj.dtype == jnp.uint32
+
+
+# ---- the graph stored at the kernels' padded shape -------------------------
+
+def _stored(logical, n):
+    """A logical ``[n, ceil(n/32)]`` packed graph laid into its stored
+    shape, the padding zero."""
+    rows, words = graph_ops.stored_shape(n, n)
+    out = np.zeros((rows, words), np.uint32)
+    out[:n, :logical.shape[1]] = np.asarray(logical)
+    return jnp.asarray(out)
+
+
+@pytest.mark.parametrize("n", [1000, 4097])
+def test_stored_graph_prune_and_hop_match_the_reference(n):
+    """User counts neither block divides: the stored (padded) graph run
+    through the Pallas kernels as it is gives the real users the prune
+    bits and hop labels that ``ref.py`` gives on the logical graph."""
+    from repro.kernels.graph import ref
+    rng = np.random.default_rng(n)
+    d = 5
+    v = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    occ = jnp.asarray(rng.integers(0, 40, n).astype(np.int32))
+    cb = clustering.cb_width(occ)
+    logical = graph_ops.pack_bits(jnp.asarray(random_sym_adj(rng, n, 0.5)))
+    stored = _stored(logical, n)
+    assert stored.shape != logical.shape
+    kw = dict(use_pallas=True, interpret=True)
+
+    want = ref.prune_packed_ref(logical, v, cb, v, cb, 1.6)
+    got = graph_ops.prune_packed(stored, v, cb, v, cb, 1.6, **kw)
+    assert got.shape == stored.shape
+    np.testing.assert_array_equal(np.asarray(graph_ops.user_rows(got, n)),
+                                  np.asarray(want))
+    assert not np.asarray(got[n:]).any()
+    assert not np.asarray(got[:, logical.shape[1]:]).any()
+
+    labels = jnp.asarray(rng.permutation(n).astype(np.int32))
+    want = ref.cc_hop_packed_ref(want, labels, labels)
+    got = graph_ops.cc_hop_packed(got, labels, labels, **kw)
+    assert got.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_stored_graph_components_match_the_dense_oracle():
+    n = 1000
+    rng = np.random.default_rng(3)
+    dense = random_sym_adj(rng, n, 0.002)
+    stored = _stored(graph_ops.pack_bits(jnp.asarray(dense)), n)
+    want = clustering.connected_components(jnp.asarray(dense))
+    for kind in ("reference", "pallas"):
+        gb = backend.BackendConfig.create(kind).graph(n, interpret=True)
+        np.testing.assert_array_equal(np.asarray(gb.cc(stored)),
+                                      np.asarray(want), err_msg=kind)
+
+
+@pytest.mark.parametrize("n,shards", [(1000, 1), (4097, 1), (1000, 4)])
+def test_init_stored_adj_holds_the_full_graph_per_user(n, shards):
+    adj = graph_ops.init_stored_adj(n, shards)
+    rows, words = graph_ops.stored_shape(n // shards, n)
+    assert adj.shape == (shards * rows, words)
+    np.testing.assert_array_equal(
+        np.asarray(graph_ops.unpack_bits(
+            graph_ops.user_rows(adj, n, shards), n)),
+        np.asarray(clustering.dense_adj(n)))
+    blocks = np.asarray(adj).reshape(shards, rows, words)
+    assert not blocks[:, n // shards:].any()          # padded rows
+
+
+def test_distclub_stored_graph_reference_vs_pallas_bit_identical():
+    """``distclub.run`` at a user count no block divides: the reference
+    and Pallas engines carry bit-identical graphs and labels."""
+    N, D, K = 1000, 5, 10
+    hyper = BanditHyper(sigma=4, max_rounds=8, gamma=0.3, n_candidates=K)
+    e, _ = env.make_synthetic_env(jax.random.PRNGKey(0), N, D, 8, K)
+    ops = env_ops.synthetic_ops(e)
+    out = []
+    for kind in ("reference", "pallas"):
+        bc = backend.BackendConfig.create(kind)
+        st, _, _ = distclub.run(ops, jax.random.PRNGKey(1), hyper,
+                                n_epochs=2, d=D,
+                                backend=bc.interact(N, D, K, interpret=True),
+                                graph=bc.graph(N, interpret=True))
+        out.append(st)
+    s_r, s_p = out
+    assert s_p.graph.adj.shape == graph_ops.stored_shape(N, N)
+    np.testing.assert_array_equal(np.asarray(s_p.graph.adj),
+                                  np.asarray(s_r.graph.adj))
+    np.testing.assert_array_equal(np.asarray(s_p.graph.labels),
+                                  np.asarray(s_r.graph.labels))
+    assert not np.asarray(s_p.graph.adj[N:]).any()
+    kept = int(graph_ops.unpack_bits(s_p.graph.adj[:N], N).sum())
+    assert 0 < kept < N * (N - 1)                  # the prune did work

@@ -21,6 +21,8 @@ Covers the PR acceptance criteria:
   * `Guarded` telemetry: the skip ratio lands in ``ema_tiles_skipped``
     and the recall probe (vs the unpruned oracle) stays 1.0.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -487,3 +489,58 @@ def test_guarded_pruned_telemetry_and_recall(tmp_path):
     # pruning is exact, so the unpruned-oracle recall probe saturates
     assert g.gs.ema_recall == pytest.approx(1.0)
     assert not g.tripped
+
+
+def test_pruned_transaction_counts_its_fold_passes():
+    """``RetrievalMetrics.fold_passes`` is the passes the duplicate-safe
+    fold ran: the largest multiplicity of a valid user in the batch (0
+    for an all-padding batch, which folds nothing)."""
+    n_users, d, N = 32, 8, 512
+    cat, e = _region_catalog(jax.random.PRNGKey(31), N, d)
+    reward_fn = _reward_fn_for(e.theta[:n_users])
+    cl = serve.build_clusters(cat, tile_items=64)
+    sess = _mk_session(n_users, d)
+    batches = {1: jnp.arange(16, dtype=jnp.int32),
+               3: jnp.asarray([5, 1, 5, -1, 2, 5, 1, 40], jnp.int32),
+               0: jnp.full((8,), -1, jnp.int32)}
+    for want, uids in batches.items():
+        sess, _, m, rm = serve.step_catalog(
+            sess, jax.random.PRNGKey(want), uids, cat, reward_fn,
+            k_short=16, clusters=cl)
+        assert int(rm.fold_passes) == want, (want, uids)
+    out = serve.recommend_catalog(sess, batches[3], cat, k_short=16,
+                                  clusters=cl)
+    assert int(out[-1].fold_passes) == 0          # recommending folds nothing
+
+
+def test_donated_transaction_matches_and_consumes_its_state():
+    """``step_catalog(..., donate=True)`` serves the same items and leaves
+    the same state as the transaction that keeps its input, duplicate
+    users (several fold passes) and a refresh included; the state passed
+    in is consumed."""
+    n_users, d, N = 32, 8, 512
+    cat, e = _region_catalog(jax.random.PRNGKey(37), N, d)
+    reward_fn = _reward_fn_for(e.theta[:n_users])
+    cl = serve.build_clusters(cat, tile_items=64)
+    uids = jnp.asarray([5, 1, 5, -1, 2, 5, 1, 40, 9, 30], jnp.int32)
+    kept = serve.OnlineBandit.create(n_users, d, HYPER, policy="distclub",
+                                     refresh_every=16)
+    # 8 valid requests close the budget: the transaction refreshes
+    kept = dataclasses.replace(kept, state=kept.state._replace(
+        since_refresh=jnp.asarray(8, jnp.int32)))
+    given = dataclasses.replace(kept, state=jax.tree.map(jnp.copy,
+                                                         kept.state))
+    key = jax.random.PRNGKey(4)
+    a, items_a, _, rm_a = serve.step_catalog(kept, key, uids, cat,
+                                             reward_fn, k_short=16,
+                                             clusters=cl)
+    b, items_b, _, rm_b = serve.step_catalog(given, key, uids, cat,
+                                             reward_fn, k_short=16,
+                                             clusters=cl, donate=True)
+    assert int(a.state.since_refresh) == 0               # the refresh fired
+    assert int(rm_a.fold_passes) == int(rm_b.fold_passes) == 3
+    np.testing.assert_array_equal(np.asarray(items_a), np.asarray(items_b))
+    for x, y in zip(jax.tree.leaves(a.state), jax.tree.leaves(b.state)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert not kept.state.Minv.is_deleted()
+    assert given.state.Minv.is_deleted() and given.state.adj.is_deleted()

@@ -13,6 +13,7 @@ from __future__ import annotations
 import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.core import distclub, env, env_ops
@@ -126,3 +127,43 @@ def test_sharded_epoch_carries_the_stage_scopes():
     line = next(l for l in out.splitlines() if l.startswith("PARTS "))
     parts = set(line.split()[1:])
     assert set(STAGE_SCOPES) <= parts, sorted(set(STAGE_SCOPES) - parts)
+
+
+SERVE_SCOPES = ("serve", "gather_score", "tile_bounds", "retrieve",
+                "choose", "env_rewards", "fold", "refresh", "stage2",
+                "prune", "cc")
+
+
+def _serve_reward(key, uids, ctx, slot):
+    x = jnp.take_along_axis(ctx, slot[:, None, None], axis=1)[:, 0]
+    return (jnp.sum(x, axis=-1) > 0).astype(ctx.dtype)
+
+
+@pytest.mark.parametrize("kind", ["reference", "pallas"])
+def test_catalog_transaction_carries_the_serve_scopes(kind):
+    """The cluster-pruned catalog transaction names its parts, and every
+    instruction of its entry computation falls under ``serve``."""
+    from repro import serve
+    from repro.serve import session as session_mod
+    sess = serve.OnlineBandit.create(N, D, HYPER, policy="distclub",
+                                     refresh_every=N, backend=kind)
+    cat = serve.random_catalog(jax.random.PRNGKey(2), 1024, D)
+    clusters = serve.build_clusters(cat, tile_items=256, kind=kind)
+    rb = session_mod._retrieval_engine(sess, 16)
+    fn = session_mod._catalog_step_fn(sess.policy, rb, _serve_reward, None,
+                                      (), True)
+    uids = jnp.arange(32, dtype=jnp.int32)
+    hlo = fn.lower(sess.state, jax.random.PRNGKey(3), uids, cat,
+                   clusters).as_text(dialect="hlo", debug_info=True)
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    parts = {p for n in names for p in n.split("/")}
+    assert set(SERVE_SCOPES) <= parts, sorted(set(SERVE_SCOPES) - parts)
+    paths = {"/".join(p for p in n.split("/") if p in SERVE_SCOPES)
+             for n in names}
+    assert {"serve/refresh/stage2/prune", "serve/tile_bounds",
+            "serve/retrieve", "serve/fold"} <= paths
+
+    comps, entry = _computations(hlo)
+    unscoped = [(name, opcode, on) for name, opcode, on in
+                _unscoped(comps[entry]) if "serve" not in on.split("/")]
+    assert unscoped == []

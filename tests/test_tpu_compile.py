@@ -108,3 +108,61 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_compile_cache):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert kernel in text
+
+
+def _serve_reward(key, uids, ctx, slot):
+    return jax.random.bernoulli(key, 0.5, uids.shape).astype(jnp.float32)
+
+
+def test_donated_fold_copies_no_table_per_pass(one_chip, no_compile_cache,
+                                               monkeypatch):
+    """The donated catalog transaction at d=19, where a TPU stores the
+    ``[n, 19, 19]`` inverse-Gram table with its users on the lanes: the
+    fold's passes scatter into a row-major table, so the compiled program
+    copies no ``[n, 19, 19]`` table inside a loop (unfixed, XLA copied the
+    whole table into the gather's layout on every pass)."""
+    import re
+    from repro import serve
+    from repro.core.types import BanditHyper
+    from repro.serve import policies as pol
+    from repro.serve import session as session_mod
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, items, batch = 20480, 19, 4096, BATCH
+    cfg = pol.make_cfg(n, d, BanditHyper(alpha=ALPHA, gamma=GAMMA,
+                                         n_candidates=K_SHORT),
+                       refresh_every=16 * batch, backend="pallas",
+                       interpret=False, precision="f32")
+    policy = pol.get_policy("distclub", cfg)
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    state = jax.tree.map(S, jax.eval_shape(policy.init))
+    cat = jax.tree.map(S, jax.eval_shape(
+        lambda e: serve.make_catalog(e, precision="f32"),
+        jax.ShapeDtypeStruct((items, d), jnp.float32)))
+    clusters = jax.tree.map(S, jax.eval_shape(
+        lambda c: serve.build_clusters(c, tile_items=TILE, kind="reference"),
+        cat))
+
+    def stored(leaf):
+        if leaf.ndim < 2:
+            return None
+        return (jax.jit(lambda x: x).lower(leaf).compile()
+                .input_formats[0][0].layout.major_to_minor)
+    layouts = tuple(stored(leaf) for leaf in jax.tree.leaves(state))
+    assert layouts[0] == (1, 2, 0)             # Minv: users on the lanes
+    rb = session_mod._retrieval_engine(
+        serve.OnlineBandit(policy=policy, state=None), K_SHORT)
+    fn = session_mod._catalog_step_fn(policy, rb, _serve_reward, None, (),
+                                      True, True, layouts)
+    text = fn.lower(state, S(jnp.zeros((2,), jnp.uint32)),
+                    S(jnp.zeros((batch,), jnp.int32)), cat,
+                    clusters).compile().as_text()
+    assert "input_output_alias={ {0}: (0" in text       # the state donated
+    bodies = set(re.findall(r"body=(%[\w.\-]+)", text))
+    where, table_copies = None, []
+    for line in text.splitlines():
+        if line.startswith("%") or line.startswith("ENTRY"):
+            where = line.split()[0]
+        if re.match(rf"\s*%copy[\w.\-]* = f32\[{n},{d},{d}\]", line):
+            table_copies.append(where)
+    assert table_copies and not [w for w in table_copies if w in bodies], \
+        table_copies
