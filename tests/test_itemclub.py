@@ -35,8 +35,8 @@ from repro.core import env, itemclub
 from repro.core.backend import BackendConfig
 from repro.core.types import BanditHyper
 from repro.kernels.topk import ops as topk_ops
-from repro.kernels.topk.ref import (BOUND_SLACK, tile_bounds, topk_ref,
-                                    topk_ref_pruned)
+from repro.kernels.topk.ref import (BOUND_SLACK, lmax_upper, tile_bounds,
+                                    topk_ref, topk_ref_pruned)
 from repro.train.checkpoint import CheckpointManager
 
 from test_distributed import _run_with_devices
@@ -91,6 +91,43 @@ def test_tile_bounds_dominate_member_scores():
     # and the slack is not doing the work: the margin is the real bound
     assert np.all(np.asarray(tb) - BOUND_SLACK + 1e-3
                   >= np.asarray(per_tile_max))
+
+
+def _inverse_grams(family, n, d, seed):
+    """``[n, d, d]`` f32 inverse Grams ``(lam I + X'X)^-1`` of one kind."""
+    rng = np.random.default_rng(seed)
+    lam = 2.0
+    if family == "near_eye":            # little history: Minv ~ I/lam
+        X = 0.05 * rng.standard_normal((n, 2, d))
+    elif family == "rank1_updates":     # a few rank-1 folds
+        X = rng.standard_normal((n, 3, d))
+    else:                               # many folds, ill-conditioned
+        lam = 1e-2
+        X = rng.standard_normal((n, 400, d)) * np.logspace(-2, 1, d)
+    M = lam * np.eye(d) + np.einsum("nka,nkb->nab", X, X)
+    Minv = np.linalg.inv(M).astype(np.float32)
+    if family == "bf16_state":          # the state stored bf16, cast back
+        Minv = np.asarray(jnp.asarray(Minv, jnp.bfloat16), np.float32)
+    return Minv
+
+
+@pytest.mark.parametrize("d", [16, 19, 25])
+@pytest.mark.parametrize("family", ["near_eye", "rank1_updates",
+                                    "many_updates", "bf16_state"])
+def test_lmax_upper_bounds_largest_eigenvalue(family, d):
+    """``lmax_upper`` is a sound majorant of every inverse Gram's largest
+    eigenvalue (float64 ``eigvalsh`` of the f32 matrix as stored), and a
+    tight one: within 1.25x, so a looser bound that would quietly stop
+    pruning from skipping fails here rather than only on the chip."""
+    Minv = _inverse_grams(family, 64, d, seed=d)
+    A = Minv.astype(np.float64)
+    ev = np.linalg.eigvalsh(0.5 * (A + A.transpose(0, 2, 1)))
+    if family in ("many_updates", "bf16_state"):
+        assert np.min(ev[:, -1] / ev[:, 0]) >= 1e4
+    lmax = ev[:, -1]
+    ub = np.asarray(lmax_upper(jnp.asarray(Minv)), np.float64)
+    assert np.all(ub >= lmax), np.min(ub / lmax)
+    assert np.all(ub <= 1.25 * lmax), np.max(ub / lmax)
 
 
 @pytest.mark.parametrize("catalog_kind", ["random", "ties", "regions"])

@@ -27,6 +27,7 @@ from repro.kernels.graph.graph import cc_hop_packed_pallas, prune_packed_pallas
 from repro.kernels.interact.interact import choose_pallas
 from repro.kernels.rank1.rank1 import rank1_update_inv_pallas
 from repro.kernels.spdinv.ops import spd_inverse
+from repro.kernels.topk.ref import tile_bounds
 from repro.kernels.topk.topk import topk_pallas, topk_pruned_pallas
 
 # paper phase: padded (n, d, K) of the fused engine
@@ -108,6 +109,26 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_compile_cache):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert kernel in text
+
+
+@pytest.mark.parametrize("d", [19, 32])
+def test_tile_bounds_compile_without_eigensolver(d, one_chip,
+                                                 no_compile_cache):
+    """The per-request tile bounds at the serving widths (B=1,024 requests,
+    123 tiles: MovieLens-25M's catalog) compile to plain XLA ops: no
+    eigensolver (``EighTpu``), nor any custom call but the compiler's own
+    ``ConcatBitcast`` layout op, bounds the inverse Grams' largest
+    eigenvalue."""
+    import re
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    T = 123
+    text = tile_bounds.lower(
+        S((BATCH, d)), S((BATCH, d, d)), S((BATCH,), jnp.int32), ALPHA,
+        S((T, d)), S((T,)), S((T,)), S((T,), jnp.int32)).compile().as_text()
+    targets = set(re.findall(r'custom_call_target="([^"]+)"', text))
+    assert targets <= {"ConcatBitcast"}, targets
+    assert "eigh" not in text.lower()
 
 
 def _serve_reward(key, uids, ctx, slot):
