@@ -51,7 +51,7 @@ SEED = 0
 # serve phase: a deployment sized for one v5e chip (see the module doc)
 N_USERS, D, K_SHORT, BATCH, N_ITEMS = 65_536, 32, 64, 1_024, 2 ** 20
 USER_SEGMENTS, ITEM_REGIONS = 8, 64
-DCCB_L = 16            # DCCB buffer length, as in benchmarks/bench_paper.py
+DCCB_L = 16            # DCCB buffer length
 
 # Two f32 programs that compute one score with different summation orders
 # (the Pallas kernels on the MXU/VPU, the reference in XLA) may round it

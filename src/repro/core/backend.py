@@ -40,7 +40,7 @@ historical factories.  ``get_backend`` / ``get_graph_backend`` /
 Padding happens once per run, not once per call: the backend precomputes
 the padded dims (users to the block multiple, d/K to sublane/lane
 multiples) at construction, the drivers pad the scan-carried state a single
-time per stage via ``pad_lin``/``pad_gram``/..., and every kernel entry
+time per stage via ``pad_gram``/``pad_vec``/..., and every kernel entry
 point short-circuits when handed pre-aligned arrays.  Only the per-step
 context tensor (fresh every round) is padded inside the loop.  All padding
 is exact: zero feature columns contribute nothing to scores or updates,
@@ -67,7 +67,6 @@ from ..kernels.rank1.ref import rank1_update_inv_ref
 from ..kernels.topk import ops as topk_ops
 from ..kernels.topk.ref import tile_bounds, topk_ref, topk_ref_pruned
 from . import clustering, linucb
-from .types import LinUCBState
 
 _ENV_FLAG = "REPRO_BACKEND"
 _PRECISION_ENV_FLAG = "REPRO_PRECISION"
@@ -226,26 +225,6 @@ class InteractBackend(NamedTuple):
                            (0, self.K_pad - a.shape[1]),
                            (0, self.d_pad - a.shape[2])))
 
-    def pad_lin(self, lin: LinUCBState) -> LinUCBState:
-        if self.kind == "reference":
-            return lin
-        return LinUCBState(
-            M=self.pad_gram(lin.M),
-            Minv=self.pad_gram(lin.Minv),
-            b=self.pad_vec(lin.b),
-            occ=self.pad_users(lin.occ),
-        )
-
-    def unpad_lin(self, lin: LinUCBState) -> LinUCBState:
-        if self.kind == "reference":
-            return lin
-        return LinUCBState(
-            M=self.unpad_gram(lin.M),
-            Minv=self.unpad_gram(lin.Minv),
-            b=self.unpad_vec(lin.b),
-            occ=self.unpad_users(lin.occ),
-        )
-
     def with_users(self, n: int) -> "InteractBackend":
         """The same engine re-fit to a different leading (user/request)
         width — d, K and the dispatch decision are kept.  The serving
@@ -299,17 +278,6 @@ class InteractBackend(NamedTuple):
             interpret=self.interpret, k_live=self.K,
         )
         return x[: w.shape[0], : w.shape[1]], choice[: w.shape[0]]
-
-    def update_lin(self, lin: LinUCBState, x, r, mask) -> LinUCBState:
-        """One masked interaction for every user: M, Minv, b in one pass."""
-        if self.kind == "reference":
-            return linucb.masked_batch_update(lin, x, r, mask)
-        M, Minv, b = rank1_ops.rank1_update(
-            lin.M, lin.Minv, lin.b, x, r, mask,
-            use_pallas=True, block_users=self.block_users,
-            interpret=self.interpret,
-        )
-        return LinUCBState(M, Minv, b, lin.occ + mask.astype(jnp.int32))
 
     def update_inv(self, Minv, b, x, r, mask):
         """M-free masked update (the sharded runtime carries no M)."""

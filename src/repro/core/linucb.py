@@ -16,7 +16,7 @@ the per-interaction cost from O(d^3) to O(d^2).  ``tests/test_linucb.py``
 checks it against explicit solves.
 
 The batched versions below are the *reference* implementations; the Pallas
-kernels in ``repro.kernels.ucb`` / ``repro.kernels.rank1`` implement the
+kernels in ``repro.kernels.interact`` / ``repro.kernels.rank1`` implement the
 same contracts for the TPU hot path and are validated against these.
 """
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Public entry point for the fused choose: pads, dispatches, unpads.
 
-Padding policy matches ``kernels/ucb``: d to the f32 sublane multiple, K to
-the lane multiple, users to the block multiple.  When the caller already
-holds padded arrays (the backend engine pads state once per stage), every
-pad here is a trace-time no-op — no copies are issued.
+Padding policy (``kernels/pad.padded_dims``): d to the f32 sublane
+multiple, K to the lane multiple, users to the block multiple.  When the
+caller already holds padded arrays (the backend engine pads state once per
+stage), every pad here is a trace-time no-op — no copies are issued.
 """
 from __future__ import annotations
 

@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from ..pad import SUB, round_up, user_block
-from .rank1 import rank1_update_inv_pallas, rank1_update_pallas
-from .ref import rank1_update_inv_ref, rank1_update_ref
+from .rank1 import rank1_update_inv_pallas
+from .ref import rank1_update_inv_ref
 
 
 def _dims(n: int, d: int, block_users: int):
@@ -17,54 +17,6 @@ def _dims(n: int, d: int, block_users: int):
     (double-buffered in and out) well inside VMEM."""
     np_, bu = user_block(n, block_users)
     return np_, round_up(d, SUB), math.gcd(bu, 64)
-
-
-def rank1_update(
-    M, Minv, b, x, r, mask,
-    *,
-    use_pallas: bool | None = None,
-    block_users: int = 256,
-    interpret: bool | None = None,
-):
-    """(M', Minv', b') — fused masked Sherman-Morrison update.
-
-    Zero-padding users is exact (mask=0 rows are identity updates).  When
-    the inputs are already block/sublane aligned (the backend engine pads
-    state once per stage) no pad copies are issued.
-    """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return rank1_update_ref(M, Minv, b, x, r, mask)
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    n, d = b.shape
-    np_, dp, bu = _dims(n, d, block_users)
-
-    if (n, d) == (np_, dp):
-        Mo, Minvo, bo = rank1_update_pallas(
-            M, Minv, b, x, r, mask.astype(jnp.float32),
-            block_users=bu, interpret=interpret,
-        )
-        return Mo, Minvo, bo
-
-    def pad2(a):
-        out = jnp.zeros((np_, dp, dp), a.dtype).at[:n, :d, :d].set(a)
-        # keep padded diagonal at 1 so Minv stays well-conditioned
-        i = jnp.arange(d, dp)
-        return out.at[:, i, i].set(jnp.ones((), a.dtype))
-
-    Mp, Minvp = pad2(M), pad2(Minv)
-    bp = jnp.zeros((np_, dp), jnp.float32).at[:n, :d].set(b)
-    xp = jnp.zeros((np_, dp), jnp.float32).at[:n, :d].set(x)
-    rp = jnp.zeros((np_,), jnp.float32).at[:n].set(r)
-    mp = jnp.zeros((np_,), jnp.float32).at[:n].set(mask.astype(jnp.float32))
-
-    Mo, Minvo, bo = rank1_update_pallas(
-        Mp, Minvp, bp, xp, rp, mp, block_users=bu, interpret=interpret
-    )
-    return Mo[:n, :d, :d], Minvo[:n, :d, :d], bo[:n, :d]
 
 
 def rank1_update_inv(

@@ -1,11 +1,9 @@
 """Fused masked Sherman-Morrison rank-1 update Pallas kernel.
 
-Per interaction the bandit touches, per user: M (+= x x^T), Minv (S-M
-downdate) and b (+= r x).  Doing these as three separate XLA ops streams
-the [n,d,d] state through HBM three times; the fused kernel reads each
-user's state once into VMEM, applies all three updates, and writes once —
-the update is memory-bound, so this is a ~3x HBM-traffic cut on the state
-arrays (the §Perf hillclimb for the bandit cell measures exactly this).
+Per interaction the bandit touches, per user: Minv (S-M downdate) and b
+(+= r x).  The runtime carries no Gram matrix M (stage 2 recovers it by
+inversion), so the hot loop reads each user's state once into VMEM,
+applies both updates, and writes once: two state passes per round.
 
 Grid: one step per block of users.  The per-user matvec ``Minv x`` is a
 VPU multiply plus lane reduction over the ``[Bu, d, d]`` block (Mosaic
@@ -35,8 +33,8 @@ def _sherman_morrison(minv, xm):
     return minv - mx_col * mx_row / denom
 
 
-def _rank1_kernel(m_ref, minv_ref, b_ref, x_ref, r_ref, mask_ref,
-                  m_out, minv_out, b_out):
+def _rank1_inv_kernel(minv_ref, b_ref, x_ref, r_ref, mask_ref,
+                      minv_out, b_out):
     # Minv may arrive bf16 (Precision state_dtype): upcast once in VMEM so
     # the S-M math runs f32; for f32 inputs the astype is a no-op.
     x = x_ref[...]                 # [Bu, d]
@@ -44,28 +42,7 @@ def _rank1_kernel(m_ref, minv_ref, b_ref, x_ref, r_ref, mask_ref,
     xm = x * msk
     minv_out[...] = _sherman_morrison(
         minv_ref[...].astype(jnp.float32), xm).astype(minv_out.dtype)
-    m_out[...] = m_ref[...] + xm[:, :, None] * xm[:, None, :]
     b_out[...] = b_ref[...] + r_ref[...] * msk * x
-
-
-def _rank1_inv_kernel(minv_ref, b_ref, x_ref, r_ref, mask_ref,
-                      minv_out, b_out):
-    """M-free variant: the sharded runtime drops the Gram matrix entirely
-    (stage-2 recovers it by inversion), so its hot loop only touches Minv
-    and b — 2 state passes instead of 4."""
-    x = x_ref[...]                 # [Bu, d]
-    msk = mask_ref[...]            # [Bu, 1] (f32 0/1)
-    xm = x * msk
-    minv_out[...] = _sherman_morrison(
-        minv_ref[...].astype(jnp.float32), xm).astype(minv_out.dtype)
-    b_out[...] = b_ref[...] + r_ref[...] * msk * x
-
-
-def _specs(block_users: int, d: int):
-    bs2 = pl.BlockSpec((block_users, d, d), lambda i: (i, 0, 0))
-    bs1 = pl.BlockSpec((block_users, d), lambda i: (i, 0))
-    bs0 = pl.BlockSpec((block_users, 1), lambda i: (i, 0))
-    return bs2, bs1, bs0
 
 
 @functools.partial(jax.jit, static_argnames=("block_users", "interpret"))
@@ -82,7 +59,9 @@ def rank1_update_inv_pallas(
     n, d = b.shape
     assert n % block_users == 0
     grid = (n // block_users,)
-    bs2, bs1, bs0 = _specs(block_users, d)
+    bs2 = pl.BlockSpec((block_users, d, d), lambda i: (i, 0, 0))
+    bs1 = pl.BlockSpec((block_users, d), lambda i: (i, 0))
+    bs0 = pl.BlockSpec((block_users, 1), lambda i: (i, 0))
     return pl.pallas_call(
         _rank1_inv_kernel,
         grid=grid,
@@ -95,34 +74,3 @@ def rank1_update_inv_pallas(
         interpret=interpret,
         name="rank1_update_inv",
     )(Minv, b, x, r.reshape(n, 1), mask.reshape(n, 1))
-
-
-@functools.partial(jax.jit, static_argnames=("block_users", "interpret"))
-def rank1_update_pallas(
-    M: jnp.ndarray,      # [n, d, d]
-    Minv: jnp.ndarray,   # [n, d, d]
-    b: jnp.ndarray,      # [n, d]
-    x: jnp.ndarray,      # [n, d]
-    r: jnp.ndarray,      # [n]
-    mask: jnp.ndarray,   # [n] f32 (0/1)
-    *,
-    block_users: int = 64,
-    interpret: bool = False,
-):
-    n, d = b.shape
-    assert n % block_users == 0
-    grid = (n // block_users,)
-    bs2, bs1, bs0 = _specs(block_users, d)
-    return pl.pallas_call(
-        _rank1_kernel,
-        grid=grid,
-        in_specs=[bs2, bs2, bs1, bs1, bs0, bs0],
-        out_specs=[bs2, bs2, bs1],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d, d), jnp.float32),
-            jax.ShapeDtypeStruct((n, d, d), Minv.dtype),
-            jax.ShapeDtypeStruct((n, d), jnp.float32),
-        ],
-        interpret=interpret,
-        name="rank1_update",
-    )(M, Minv, b, x, r.reshape(n, 1), mask.reshape(n, 1))

@@ -4,33 +4,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
-def rank1_update_ref(
-    M: jnp.ndarray,       # [n, d, d]
-    Minv: jnp.ndarray,    # [n, d, d]
-    b: jnp.ndarray,       # [n, d]
-    x: jnp.ndarray,       # [n, d] chosen contexts
-    r: jnp.ndarray,       # [n]    realized rewards
-    mask: jnp.ndarray,    # [n] bool
-):
-    """Returns (M', Minv', b') after one masked interaction per user.
-
-    Minv' is the exact Sherman-Morrison inverse of M' = M + mask x x^T.
-    A masked-out user is an identity update (x -> 0 path is exact).
-    M stays f32 always; Minv may be stored bf16 (see rank1_update_inv_ref).
-    """
-    dt = Minv.dtype
-    Minv32 = Minv.astype(jnp.float32)
-    m = mask.astype(x.dtype)
-    xm = x * m[:, None]
-    Mx = jnp.sum(Minv32 * xm[:, None, :], axis=-1)     # elementwise: exact
-    denom = 1.0 + jnp.sum(xm * Mx, axis=-1)            # f32 at any precision
-    Minv_new = Minv32 - jnp.einsum("ni,nj->nij", Mx, Mx) / denom[:, None,
-                                                                 None]
-    M_new = M + jnp.einsum("ni,nj->nij", xm, xm)
-    b_new = b + (r * m)[:, None] * x
-    return M_new, Minv_new.astype(dt), b_new
-
-
 def rank1_update_inv_ref(
     Minv: jnp.ndarray,    # [n, d, d]
     b: jnp.ndarray,       # [n, d]

@@ -15,8 +15,8 @@ path when the catalog fits in one slate.
 The oracle never materializes the ``[n, N_items]`` score matrix either:
 users are processed in ``row_block`` groups via ``lax.map`` and items in
 ``item_block`` tiles via ``lax.scan``, carrying a running
-``[row_block, k_short]`` shortlist — ``N_items = 2**20`` runs on one CPU
-core in a few seconds (see ``benchmarks/bench_retrieval.py``).
+``[row_block, k_short]`` shortlist, so its working set does not grow
+with ``N_items``.
 
 Tiling invariance (load-bearing for every parity claim): each item's
 score contracts only over the feature dim, so its bits do not depend on

@@ -1,12 +1,12 @@
 """JAX's persistent compilation cache, at one fixed place per checkout.
 
-Entry points (``chip_smoke.py``, ``launch.serve``, ``launch.train``,
-``benchmarks.run``) call :func:`enable` once at start-up — never at
-import — so a run that compiles the same programs again (the next chip
-call on a machine that keeps the checkout, a second launcher in one
-session) reads them back instead of recompiling.  A later run finds the
-cache only at the same path, so the path is fixed: ``<checkout>/.jax_cache``,
-never a temporary, pid- or time-derived one.
+Entry points (``chip_smoke.py``, ``launch.serve``, ``launch.train``)
+call :func:`enable` once at start-up — never at import — so a run that
+compiles the same programs again (the next chip call on a machine that
+keeps the checkout, a second launcher in one session) reads them back
+instead of recompiling.  A later run finds the cache only at the same
+path, so the path is fixed: ``<checkout>/.jax_cache``, never a
+temporary, pid- or time-derived one.
 """
 from __future__ import annotations
 

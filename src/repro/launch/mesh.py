@@ -62,9 +62,3 @@ def batch_spec(mesh: Mesh, rank: int, sharded_dim: int = 0) -> P:
     entries[sharded_dim] = batch_axes(mesh)
     return P(*entries)
 
-
-# TPU v5e roofline constants (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW = 4.5e10               # B/s per link (~50 GB/s, 1 direction)
-HBM_BYTES = 16 * 1024 ** 3    # 16 GiB

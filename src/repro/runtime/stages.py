@@ -213,7 +213,7 @@ def cluster_rounds(be, ops, hyper, Minv, b, occ, budget, key, row0,
 def stage2_comm_bytes(n: int, d: int) -> int:
     """Modeled network bytes of one stage-2 refresh (paper Fig. 3, updated
     for the packed graph engine).  Single source of truth for both
-    drivers, the tests and the paper benchmarks.
+    drivers and the tests.
 
     Per refresh: each user ships (M, b) once into the tree reduction and
     the cluster stats return along the same tree (``2 n (d^2 + d)`` f32
@@ -222,7 +222,7 @@ def stage2_comm_bytes(n: int, d: int) -> int:
     n i32 labels — ``ceil(log2 n) + 1`` hops bound the doubling schedule.
     The adjacency itself NEVER crosses the network: it is row-sharded and
     bit-packed, n^2/8 bytes of node-local HBM (32x below the dense bool
-    graph; see ``benchmarks/bench_graph.py`` for the HBM model).
+    graph).
     """
     hops = max(1, math.ceil(math.log2(max(n, 2))) + 1)
     return 4 * (2 * n * (d * d + d) + n * (d + 1) + hops * n)

@@ -5,7 +5,7 @@ Design (DESIGN.md §6):
     (npz) plus a msgpack-free JSON manifest (step, keys, shapes, dtypes).
     No mesh/sharding info is persisted — restore re-shards onto whatever
     mesh the new job has (**elastic**: scale from 256 to 512 chips or down
-    to 1 CPU between runs; the bandit benchmarks round-trip through this).
+    to 1 CPU between runs; the serving sessions round-trip through this).
   * Writes go to ``<dir>/tmp-<step>`` then ``os.replace`` into place —
     a crashed writer never corrupts the latest checkpoint (atomicity).
   * ``keep`` most-recent checkpoints are retained; ``latest_step`` scans

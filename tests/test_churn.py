@@ -240,6 +240,70 @@ def test_conservation_identity_exact_under_churn_and_faults(spec):
     assert int(pending_mod.conservation_gap(sess.pending)) == 0
 
 
+# ---------------------------------------------------------------------------
+# seeded levels of the churn scenarios against a churn-free control
+# ---------------------------------------------------------------------------
+
+LV_USERS, LV_BATCH, LV_ITEMS, LV_ITEM_CAPACITY, LV_KS = 128, 32, 384, 512, 16
+LV_ROUNDS = 60
+# identical delay/loss faults on every scenario (the control included), so
+# the ratios isolate the churn itself
+_LV_DELIVERY = dict(seed=5, p_delay=0.25, max_delay=3, p_loss=0.05)
+
+
+@pytest.fixture(scope="module")
+def churn_levels_world():
+    e, _ = env.make_catalog_env(jax.random.PRNGKey(0), LV_USERS, D, 4,
+                                LV_ITEMS, n_candidates=K)
+    cat = serve.make_catalog(env.catalog_embeddings(e),
+                             capacity=LV_ITEM_CAPACITY)
+    nochurn = _levels_run(e, cat, faults.FaultSpec(**_LV_DELIVERY))
+    return e, cat, nochurn
+
+
+def _levels_run(e, cat, spec):
+    sess = serve.OnlineBandit.create(
+        LV_USERS, D, HYPER, policy="distclub", refresh_every=LV_USERS,
+        pending_capacity=512, pending_ttl=16)
+    _, rep = faults.run_faulted_catalog(
+        sess, e, LV_ROUNDS, spec, catalog=cat, k_short=LV_KS,
+        batch=LV_BATCH, key=11, assert_conservation=True)
+    return rep
+
+
+# floors: 90% of the seeded values these scenarios recorded at these sizes
+@pytest.mark.parametrize("spec,recorded", [
+    (None, dict(matched_ratio=0.9453125)),
+    (faults.FaultSpec(**_LV_DELIVERY, churn_every=3, churn_add=8,
+                      churn_retire=8),
+     dict(matched_ratio=0.9328125, stale_ratio=0.022916666666666665,
+          reward_vs_nochurn_ratio=1.0009496676163343)),
+    (faults.FaultSpec(**_LV_DELIVERY, churn_every=5, churn_add=8,
+                      churn_retire=8, flash_crowd_at=10,
+                      flash_crowd_size=24),
+     dict(matched_ratio=0.9458333333333333,
+          stale_ratio=0.0067708333333333336,
+          reward_vs_nochurn_ratio=0.9895536562203229)),
+], ids=["nochurn", "sustained", "flash_crowd"])
+def test_churn_levels_hold_against_nochurn(churn_levels_world, spec,
+                                           recorded):
+    """Over 60 rounds of 32 requests on 128 users and 384 items, the
+    share of decisions folded, the share quarantined as stale, and the
+    realized reward against the churn-free control on identical traffic
+    stay at or above 90% of their seeded values: churn costs little
+    learning, and the quarantine really fires."""
+    e, cat, nochurn = churn_levels_world
+    rep = nochurn if spec is None else _levels_run(e, cat, spec)
+    st = rep.pending
+    got = {"matched_ratio": st["matched"] / st["issued"],
+           "stale_ratio": st["stale"] / st["issued"],
+           "reward_vs_nochurn_ratio": rep.reward / nochurn.reward}
+    for name, value in recorded.items():
+        assert got[name] >= 0.9 * value, (name, got[name], value)
+    if spec is None:
+        assert st["stale"] == 0, st
+
+
 def test_conservation_and_parity_8dev_item_sharded():
     """The same seeded churn+faults run on an 8-device item-sharded mesh:
     the conservation identity holds after every delivery AND every final

@@ -18,7 +18,6 @@ Acceptance criteria covered here:
     checkpoints to the newest good one.
 """
 import json
-import pathlib
 import warnings
 
 import numpy as np
@@ -323,6 +322,33 @@ def test_seeded_fault_suite_bounded_degradation(world):
     assert st["unmatched"] > 0, st
 
 
+# floors: 90% of the seeded values the scenario recorded at 64 users
+@pytest.mark.parametrize("spec,recorded", [
+    (faults.FaultSpec(seed=7, p_delay=0.3, max_delay=4, p_loss=0.1,
+                      p_dup=0.05),
+     dict(matched_ratio=0.8854166666666666,
+          reward_vs_clean_ratio=0.9675090252707581)),
+], ids=["delay_loss_dup"])
+def test_fault_levels_hold_against_clean(spec, recorded):
+    """Over 30 rounds of 16 requests on 64 users, the share of decisions
+    folded and the realized reward against the clean control on
+    identical traffic stay at or above 90% of their seeded values."""
+    e, _ = env.make_synthetic_env(jax.random.PRNGKey(0), 64, D, 4, K)
+
+    def run(fault_spec):
+        sess = serve.OnlineBandit.create(
+            64, D, HYPER, policy="distclub", refresh_every=64,
+            pending_capacity=256, pending_ttl=16)
+        return faults.run_faulted(sess, e.theta, 30, fault_spec, batch=B,
+                                  key=11)[1]
+
+    clean, rep = run(faults.FaultSpec()), run(spec)
+    got = {"matched_ratio": rep.pending["matched"] / rep.pending["issued"],
+           "reward_vs_clean_ratio": rep.reward / clean.reward}
+    for name, value in recorded.items():
+        assert got[name] >= 0.9 * value, (name, got[name], value)
+
+
 def test_stall_backlog_floods_then_drains(world):
     """A simulated shard stall: no delivery for `stall_rounds`, then the
     backlog floods in — everything still folds exactly once."""
@@ -479,77 +505,6 @@ def test_session_restore_survives_torn_latest(world, tmp_path):
     redo, _, _ = serve.step(restored, jax.random.PRNGKey(2), _uids(2),
                             _ctx(2), reward_fn)
     _assert_states_equal(redo.state, good.state)
-
-
-# ---------------------------------------------------------------------------
-# bench-gate hygiene: missing baseline is a clear failure
-# ---------------------------------------------------------------------------
-
-
-def test_check_regression_missing_baseline_clear_message(tmp_path):
-    import subprocess
-    import sys
-    repo = pathlib.Path(__file__).resolve().parents[1]
-    cur = tmp_path / "cur"
-    base = tmp_path / "base"
-    cur.mkdir()
-    base.mkdir()
-    (cur / "BENCH_thing.json").write_text(json.dumps(
-        {"rows": [{"name": "r", "some_ratio": 1.0}]}))
-    out = subprocess.run(
-        [sys.executable, str(repo / "benchmarks" / "check_regression.py"),
-         "--current", str(cur), "--baseline", str(base)],
-        capture_output=True, text=True)
-    assert out.returncode == 1
-    blob = out.stdout + out.stderr
-    assert "no baseline" in blob and "BENCH_thing.json" in blob
-    assert "Traceback" not in blob
-
-
-def test_check_regression_perturbed_baseline_fails_per_direction(tmp_path):
-    """Perturb-a-baseline self-test of the gate's direction rules: every
-    name class trips on a regression in ITS direction (including the
-    lower-better ``*flip_rate*``/``*error*`` precision metrics) and
-    stays green on same-direction improvements."""
-    import subprocess
-    import sys
-    repo = pathlib.Path(__file__).resolve().parents[1]
-    good = {"rows": [{"name": "r", "hbm_cut_ratio": 2.0,
-                      "comm_bytes": 100.0, "choice_flip_rate": 0.004,
-                      "bound_error": 0.5}]}
-
-    def run(current_rows):
-        cur = tmp_path / "cur"
-        base = tmp_path / "base"
-        for p in (cur, base):
-            p.mkdir(exist_ok=True)
-        (base / "BENCH_thing.json").write_text(json.dumps(good))
-        (cur / "BENCH_thing.json").write_text(json.dumps(current_rows))
-        return subprocess.run(
-            [sys.executable,
-             str(repo / "benchmarks" / "check_regression.py"),
-             "--current", str(cur), "--baseline", str(base)],
-            capture_output=True, text=True)
-
-    out = run(good)
-    assert out.returncode == 0, out.stdout + out.stderr
-
-    regressions = {"hbm_cut_ratio": 1.0,       # higher-better fell
-                   "comm_bytes": 200.0,        # lower-better rose
-                   "choice_flip_rate": 0.02,   # precision parity worsened
-                   "bound_error": 1.5}
-    for key, bad_val in regressions.items():
-        rows = {"rows": [dict(good["rows"][0], **{key: bad_val})]}
-        out = run(rows)
-        blob = out.stdout + out.stderr
-        assert out.returncode == 1, (key, blob)
-        assert key in blob, (key, blob)
-
-    improvements = {"hbm_cut_ratio": 4.0, "comm_bytes": 50.0,
-                    "choice_flip_rate": 0.0, "bound_error": 0.1}
-    rows = {"rows": [dict(good["rows"][0], **improvements)]}
-    out = run(rows)
-    assert out.returncode == 0, out.stdout + out.stderr
 
 
 # ---------------------------------------------------------------------------

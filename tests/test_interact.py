@@ -82,7 +82,8 @@ def test_fused_choose_padded_candidates_never_win():
     np.testing.assert_allclose(x, x_ref, atol=1e-6)
 
 
-@pytest.mark.parametrize("n,d", [(37, 25), (64, 32)])
+@pytest.mark.parametrize("n,d", [(8, 8), (37, 25), (64, 32), (100, 19),
+                                 (256, 32)])
 def test_rank1_inv_kernel(n, d):
     key = jax.random.PRNGKey(n + d)
     ks = jax.random.split(key, 5)
@@ -119,14 +120,17 @@ def test_backend_pad_helpers_are_exact():
     be = backend.BackendConfig.create("pallas").interact(24, 5, 10,
                                                          interpret=True)
     lin = linucb.init_linucb(24, 5)
-    padded = be.pad_lin(lin)
-    assert padded.Minv.shape == (be.n_pad, be.d_pad, be.d_pad)
+    Minv = be.pad_gram(lin.Minv)
+    assert Minv.shape == (be.n_pad, be.d_pad, be.d_pad)
     # padded Gram blocks are identity (well-conditioned), real block intact
     np.testing.assert_allclose(
-        padded.Minv, jnp.broadcast_to(jnp.eye(be.d_pad),
-                                      (be.n_pad, be.d_pad, be.d_pad)))
-    back = be.unpad_lin(padded)
-    for a, b_ in zip(back, lin):
+        Minv, jnp.broadcast_to(jnp.eye(be.d_pad),
+                               (be.n_pad, be.d_pad, be.d_pad)))
+    b = be.pad_vec(lin.b)
+    occ = be.pad_users(lin.occ)
+    assert b.shape == (be.n_pad, be.d_pad) and occ.shape == (be.n_pad,)
+    back = (be.unpad_gram(Minv), be.unpad_vec(b), be.unpad_users(occ))
+    for a, b_ in zip(back, (lin.Minv, lin.b, lin.occ)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
 

@@ -8,8 +8,7 @@ Covers the API redesign's acceptance criteria:
   * bf16/int8 sessions bound the per-decision choice-flip rate vs the
     f32 oracle on seeded traffic (counterfactual probes on the oracle's
     own trajectory — occ/b stay exact, flips come only from the score
-    contraction; see benchmarks/bench_precision.py for the full-size
-    gated run);
+    contraction), at test scale and at 256 users x 4,096 items;
   * int8 per-slot dequant scales survive catalog churn: staged
     retire/add, double-buffered publish, and slot reclaim keep every
     untouched slot's codes+scale bit-identical and give churn-added rows
@@ -173,42 +172,85 @@ def test_f32_sharded_8dev_matches_single_host():
 # reduced-precision flip-rate bound
 # ---------------------------------------------------------------------------
 
-def test_bf16_int8_choice_flip_rate_bounded():
-    """Counterfactual per-decision probes on the f32 oracle's trajectory
-    (the bench_precision harness at test scale): after the cold-start
-    warmup, bf16/int8 flip at most 2% of choices — and the reduced
-    sessions really store reduced state."""
-    emb, theta = _world()
+def _flip_counts(emb, theta, session, uids, warm, meas, ks, batch):
+    """Counterfactual choice flips of bf16 and int8 against the f32
+    oracle's own trajectory: each measured round, the oracle's decision is
+    compared with the decision from the SAME state cast to the reduced
+    state dtype, against the reduced catalog.  Returns (flips, total)."""
     rf = _reward_fn(theta)
-    oracle = _session(None)
+    oracle = session(None)
     cat = serve.make_catalog(emb)
-    probes = {}
-    for p in ("bf16", "int8"):
-        rs = _session(p)
-        assert rs.state.Minv.dtype == jnp.bfloat16
-        probes[p] = (rs, serve.make_catalog(emb, precision=p))
-    assert probes["int8"][1].serving.emb.dtype == jnp.int8
-    warm, meas = 20, 8
+    probes = {p: (session(p), serve.make_catalog(emb, precision=p))
+              for p in ("bf16", "int8")}
     flips = {p: 0 for p in probes}
     total = 0
     for t in range(warm + meas):
-        u = _uids(t)
+        u = uids(t)
         if t >= warm:
-            idf, _, _ = serve.recommend_catalog(oracle, u, cat, k_short=KS)
-            total += B
+            idf, _, _ = serve.recommend_catalog(oracle, u, cat, k_short=ks)
+            total += batch
             for p, (rs, catp) in probes.items():
                 sdt = rs.policy.cfg.engine.precision.jnp_state
                 st = oracle.state._replace(
                     Minv=oracle.state.Minv.astype(sdt),
                     uMcinv=oracle.state.uMcinv.astype(sdt))
                 idr, _, _ = serve.recommend_catalog(
-                    dataclasses.replace(rs, state=st), u, catp, k_short=KS)
+                    dataclasses.replace(rs, state=st), u, catp, k_short=ks)
                 flips[p] += int(jnp.sum(idf != idr))
         oracle, _, _ = serve.step_catalog(oracle,
                                           jax.random.PRNGKey(1000 + t), u,
-                                          cat, rf, k_short=KS)
+                                          cat, rf, k_short=ks)
+    return flips, total
+
+
+def test_bf16_int8_choice_flip_rate_bounded():
+    """Counterfactual per-decision probes on the f32 oracle's trajectory
+    (``_flip_counts`` at test scale): after the cold-start warmup,
+    bf16/int8 flip at most 2% of choices — and the reduced sessions
+    really store reduced state."""
+    emb, theta = _world()
+    for p in ("bf16", "int8"):
+        assert _session(p).state.Minv.dtype == jnp.bfloat16
+    assert serve.make_catalog(emb, precision="int8").serving.emb.dtype \
+        == jnp.int8
+    flips, total = _flip_counts(emb, theta, _session, _uids, 20, 8, KS, B)
     for p, f in flips.items():
         assert f / total <= 0.02, (p, f, total)
+
+
+@pytest.fixture(scope="module")
+def full_size_flips():
+    """bf16/int8 flips at 256 users, 4,096 distinct items, d = 32, a
+    64-item shortlist and 64 requests a round: 32 warm-up rounds, then 40
+    measured (2,560 decisions)."""
+    d, ks, n_users, n_items, batch = 32, 64, 256, 4096, 64
+    k = jax.random.normal(jax.random.PRNGKey(7), (n_items, d))
+    emb = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    th = jax.random.normal(jax.random.PRNGKey(8), (n_users, d))
+    theta = th / jnp.linalg.norm(th, axis=-1, keepdims=True)
+    hyper = BanditHyper(alpha=0.05, gamma=1.5, n_candidates=ks)
+
+    def session(precision):
+        return serve.OnlineBandit.create(n_users, d, hyper,
+                                         policy="distclub", refresh_every=0,
+                                         backend="reference",
+                                         precision=precision)
+
+    def uids(t):
+        return jax.random.permutation(jax.random.PRNGKey(100 + t),
+                                      n_users)[:batch].astype(jnp.int32)
+
+    return _flip_counts(emb, theta, session, uids, 32, 40, ks, batch)
+
+
+# ceilings: 110% of the seeded flip rates recorded at this size
+@pytest.mark.parametrize("prec,recorded", [("bf16", 0.0015625),
+                                           ("int8", 0.001953125)])
+def test_choice_flip_rate_holds_at_full_size(full_size_flips, prec,
+                                             recorded):
+    flips, total = full_size_flips
+    assert total == 2560
+    assert flips[prec] / total <= 1.1 * recorded, (prec, flips[prec])
 
 
 # ---------------------------------------------------------------------------

@@ -251,14 +251,3 @@ def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
             assert jax.config.jax_compilation_cache_dir == before
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_bench_cpu_child_is_pinned_to_cpu_and_fails_loudly():
-    """Benchmark children never ask for the accelerator (the parent holds
-    it) and a failed child fails its bench instead of leaving a row."""
-    from benchmarks.common import run_cpu_child
-    out = run_cpu_child("import jax; print(jax.default_backend(), "
-                        "len(jax.devices()))", 2)
-    assert out.split() == ["cpu", "2"]
-    with pytest.raises(RuntimeError, match="exit 3"):
-        run_cpu_child("import sys; sys.exit(3)", 1)
